@@ -46,6 +46,8 @@ import jax.numpy as jnp
 from benchmarks.common import (
     SCALE, bench_collections, emit, time_batched, write_json,
 )
+from repro.analysis.jaxpr import count_primitive
+from repro.common import enable_compile_cache
 from repro.core.csa import build_csa, csa_search_batch, csa_search_planned
 from repro.core.sada import build_sada
 from repro.core.suffix import build_suffix_data, subcollection
@@ -57,13 +59,6 @@ BATCH_SIZES = (1, 16, 128)
 SHARD_COUNTS = (1, 2, 4, 8)
 
 
-def count_eqns(jaxpr, name: str) -> int:
-    total = sum(1 for eqn in jaxpr.eqns if eqn.primitive.name == name)
-    for sub in jax.core.subjaxprs(jaxpr):
-        total += count_eqns(sub, name)
-    return total
-
-
 def _workload(coll, B: int, rng):
     pats = random_substring_patterns(coll, max(2 * B, 16), 4, 24)
     idx = rng.integers(0, len(pats), B)
@@ -72,10 +67,7 @@ def _workload(coll, B: int, rng):
 
 
 def _resident_bytes(csa):
-    return ops.backward_search_resident_bytes(
-        csa.wm.words, csa.wm.ones_prefix, csa.wm.zcount,
-        csa.counts[: csa.sigma] - csa.wm.sym_starts,
-    )
+    return ops.backward_search_resident_bytes(csa.wm.words, csa.wm.ones_prefix)
 
 
 def _sharded_plan_variants(coll, n_shards: int):
@@ -166,8 +158,8 @@ def run(collections=("version-p001", "dna-p03"), batch_sizes=BATCH_SIZES,
             ref_lo, ref_hi = search_variants["legacy-dual-descent"](pats, lens)
             for variant, (fn, mesh_shape, resident) in meta.items():
                 closed = jax.make_jaxpr(fn)(pats, lens)
-                launches = count_eqns(closed.jaxpr, "pallas_call")
-                gathers = count_eqns(closed.jaxpr, "gather")
+                launches = count_primitive(closed.jaxpr, "pallas_call")
+                gathers = count_primitive(closed.jaxpr, "gather")
                 med, got = time_batched(fn, pats, lens, iters=iters)
                 # every variant must agree on the integers
                 if variant in search_variants:
@@ -221,6 +213,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: one collection, tiny batches, 2 iters")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         run(collections=("version-p001",), batch_sizes=(1, 16), iters=2,
             out=args.out, shard_counts=tuple(args.shards))

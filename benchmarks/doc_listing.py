@@ -34,7 +34,7 @@ from repro.core.pdl import build_pdl, pdl_list_docs
 from repro.core.wtlist import build_da_wavelet, wt_list_docs, wt_modeled_bits
 from repro.kernels import ops
 from repro.succinct.rmq import rmq_build
-from repro.common import ceil_log2
+from repro.common import ceil_log2, enable_compile_cache
 
 
 def run(collections=("dna-p001", "dna-p03", "version-p001", "random"),
@@ -109,9 +109,7 @@ def run(collections=("dna-p001", "dna-p03", "version-p001", "random"),
         resident = ops.ilcp_list_resident_bytes(
             ilcp.vilcp, ilcp.rmq.table, ilcp.run_starts, da
         )
-        scratch = ops.ilcp_list_scratch_bytes(
-            int(lo.shape[0]), d=coll.d, max_df=max_df
-        )
+        scratch = ops.ilcp_list_scratch_bytes(coll.d)
         for use_k in modes[list_kernel]:
             fn = jax.jit(
                 lambda a, b, ilcp=ilcp, da=da, md=max_df, uk=use_k:
@@ -142,6 +140,7 @@ def main():
     ap.add_argument("--collections", nargs="*",
                     default=["dna-p001", "dna-p03", "version-p001", "random"])
     args = ap.parse_args()
+    enable_compile_cache()
     run(collections=tuple(args.collections), list_kernel=args.list_kernel)
 
 

@@ -26,6 +26,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=SECTIONS)
     args = ap.parse_args()
+    from repro.common import enable_compile_cache
+
+    enable_compile_cache()
     todo = [args.only] if args.only else SECTIONS
 
     for section in todo:

@@ -44,6 +44,7 @@ import jax
 
 from benchmarks.common import SCALE, bench_collections, emit, write_json
 from repro.analysis.jaxpr import count_primitive
+from repro.common import enable_compile_cache
 from repro.data.collections import random_substring_patterns
 from repro.kernels import ops
 from repro.serve import faults
@@ -250,7 +251,7 @@ def run_list_kernel_comparison(collection: str, max_df: int = 128,
                 svc.trace_endpoint("list", B=B, max_df=max_df).jaxpr,
                 "pallas_call",
             )
-            scratch = ops.ilcp_list_scratch_bytes(B, d=ilcp.d, max_df=max_df)
+            scratch = ops.ilcp_list_scratch_bytes(ilcp.d)
             idx = rng.integers(0, len(workload), size=(iters + 1, B))
             batches_q = [[workload[i] for i in row] for row in idx]
             it = iter(range(10_000))
@@ -371,6 +372,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: one collection, tiny batches, 3 iters")
     args = ap.parse_args()
+    enable_compile_cache()
     lk = {"auto": None, "on": True, "off": False}[args.list_kernel]
     if args.smoke:
         run(collections=("version-p001",), batch_sizes=(1, 16), iters=3,

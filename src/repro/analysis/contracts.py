@@ -23,9 +23,9 @@ else:
   was folded into the program.
 * **VMEM budget** — each ``pallas_call``'s block shapes must fit
   ``BACKWARD_SEARCH_VMEM_BUDGET``, and an over-budget index must provably
-  fall back to XLA *at lowering time* (``backend="kernel_overbudget"``
-  contracts trace with the budget clamped to 1 byte and demand zero
-  launches).
+  be served by XLA: ``backend="kernel_overbudget"`` contracts clamp the
+  budgets to 1 byte, take the kernel flags the build would select on TPU
+  (``repro.serve.retrieval.kernel_selection``) and demand zero launches.
 
 ``build_registry`` derives the expected numbers from the service's own
 index dimensions, ``audit_service`` traces every endpoint program through
@@ -39,6 +39,7 @@ import dataclasses
 
 from repro.analysis import jaxpr as jx
 from repro.kernels import ops
+from repro.serve.retrieval import kernel_selection
 
 #: static gather slack on top of the 2-per-level pair-descent rank gathers:
 #: pattern reversal, base/sym_starts lookups, and the Sada df counting that
@@ -231,36 +232,32 @@ def audit_jaxpr(traced, contract: EndpointContract) -> list[Violation]:
 
 def trace_for_contract(svc, contract: EndpointContract):
     """Trace the endpoint program a contract describes, with the backend
-    forced and — for ``kernel_overbudget`` — BOTH VMEM budgets clamped so
-    an over-budget index is simulated at lowering time (the list endpoint
-    carries two kernels, and proving the fallback means proving both
-    wrappers routed to XLA, not just the search one)."""
+    forced — for ``kernel_overbudget``, to the flags the build's selection
+    picks on TPU with BOTH VMEM budgets clamped, so an over-budget index is
+    shown to be served by the XLA executors (the list endpoint carries two
+    kernels, and each needs its own selection to fall to XLA)."""
     B, m = contract.bucket
-    use_kernel = contract.backend != "xla"
-    kw = {"use_kernel": use_kernel}
-    if contract.kind == "list":
-        kw["use_list_kernel"] = use_kernel
     if contract.backend == "kernel_overbudget":
         saved = (ops.BACKWARD_SEARCH_VMEM_BUDGET, ops.ILCP_LIST_VMEM_BUDGET)
         ops.BACKWARD_SEARCH_VMEM_BUDGET = 1
         ops.ILCP_LIST_VMEM_BUDGET = 1
         try:
-            kw["use_kernel"] = True
-            if contract.kind == "list":
-                kw["use_list_kernel"] = True
-            return svc.trace_endpoint(contract.kind, B, m, **kw)
+            use_kernel, use_list_kernel = kernel_selection(
+                getattr(svc, "shards", [svc]), "tpu"
+            )
         finally:
             ops.BACKWARD_SEARCH_VMEM_BUDGET, ops.ILCP_LIST_VMEM_BUDGET = saved
-    return svc.trace_endpoint(contract.kind, B, m, **kw)
+    else:
+        use_kernel = use_list_kernel = contract.backend == "kernel"
+    return svc.trace_endpoint(contract.kind, B, m, use_kernel=use_kernel,
+                              use_list_kernel=use_list_kernel)
 
 
 def _csa_static_vmem_bytes(csa, buckets) -> int:
     """Static (metadata-level) VMEM estimate, independent of tracing: the
     same block layout the kernel wrapper will claim for this index."""
-    wm = csa.wm
-    base = csa.counts[: csa.sigma] - wm.sym_starts
     return ops.block_meta_bytes(ops.backward_search_block_meta(
-        wm.words, wm.ones_prefix, wm.zcount, base,
+        csa.wm.words, csa.wm.ones_prefix,
         batch=max(b for b, _ in buckets), max_m=max(m for _, m in buckets),
     ))
 

@@ -20,8 +20,8 @@ from __future__ import annotations
 
 import math
 
-import jax
 import numpy as np
+from jax.extend import core as jex_core
 
 #: primitives that re-enter the host mid-program; forbidden in any serving
 #: jaxpr (a host round-trip inside a batched endpoint defeats the entire
@@ -49,7 +49,7 @@ def _jaxprs_in(value):
     """Yield every jaxpr held (possibly nested in containers) in a param
     value — ``cond`` stores a tuple of ClosedJaxprs, ``pjit`` a single
     ClosedJaxpr, pallas a raw Jaxpr."""
-    if isinstance(value, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+    if isinstance(value, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
         yield _as_jaxpr(value)
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -70,12 +70,23 @@ def iter_eqns(jaxpr):
 
 
 def count_primitive(jaxpr, name: str) -> int:
-    """Whole-program occurrence count of a primitive by name.
-
-    Replaces the hand-rolled ``count_eqns`` from tests/test_kernels.py:
-    that version descended only via ``jax.core.subjaxprs`` and could miss
-    jaxprs nested inside eqn params of ``pjit``/``custom_vjp`` calls."""
-    return sum(1 for eqn in iter_eqns(jaxpr) if eqn.primitive.name == name)
+    """Whole-program occurrence count of a primitive by name: nested
+    jaxprs are descended at any depth and every ``cond`` branch counts,
+    except that a per-platform ``cond`` (``lax.platform_dependent``, whose
+    eqn carries ``branches_platforms``) counts its costliest branch — one
+    platform's branch is lowered.  The kernel wrappers stage each Pallas
+    launch that way (an interpreted CPU branch and a compiled TPU branch):
+    one launch, not two."""
+    jaxpr = _as_jaxpr(jaxpr)
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += int(eqn.primitive.name == name)
+        subs = [count_primitive(sub, name) for sub in _jaxprs_in(eqn.params)]
+        if eqn.params.get("branches_platforms") is not None:
+            total += max(subs, default=0)
+        else:
+            total += sum(subs)
+    return total
 
 
 def find_primitives(jaxpr, names) -> list:

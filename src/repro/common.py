@@ -10,11 +10,29 @@ through ``jit``/``vmap`` unchanged); integer metadata that must be *static*
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+#: the persistent compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed, git-ignored directory of the checkout (the path is part
+#: of the cache key, so a directory that moved would never hit)
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    For entry points only (never called at import).  A set
+    ``JAX_COMPILATION_CACHE_DIR`` is where the cache goes, and no other
+    directory is set; otherwise ``COMPILE_CACHE_DIR``."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 # ---------------------------------------------------------------------------
 # dtype policy

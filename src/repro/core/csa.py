@@ -154,7 +154,7 @@ def csa_search_batch(csa: CSA, patterns, lengths):
     )
 
 
-def csa_search_planned(csa: CSA, patterns, lengths, *, use_kernel: bool | None = None,
+def csa_search_planned(csa: CSA, patterns, lengths, *, use_kernel: bool = False,
                        block_q: int = 256, interpret: bool | None = None):
     """Backward search written batch-first for the serving planner.
 
@@ -163,19 +163,17 @@ def csa_search_planned(csa: CSA, patterns, lengths, *, use_kernel: bool | None =
     symbol step (``wm_rank_pair_batch``) — half the per-level rank gathers
     of two independent ``wm_rank_batch`` descents.
 
-    ``use_kernel`` selects the execution path:
-      * ``None``  — auto: the fused Pallas kernel on TPU, XLA elsewhere;
-      * ``True``  — force the fused kernel (``repro.kernels.backward_search``;
-        one ``pallas_call`` for the whole batched search, interpret mode
-        off-TPU unless ``interpret`` says otherwise);
-      * ``False`` — force the XLA pair-descent path.
+    ``use_kernel`` selects the execution path (the serving layer decides it
+    once, at build time):
+      * ``True``  — the fused kernel (``repro.kernels.backward_search``; one
+        ``pallas_call`` for the whole batched search, interpreted when the
+        program is lowered for CPU unless ``interpret`` says otherwise);
+      * ``False`` — the XLA pair-descent path.
     """
     patterns = as_i32(patterns)
     lengths = as_i32(lengths)
     B, max_m = patterns.shape
 
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
         from repro.kernels.ops import backward_search
 

@@ -259,22 +259,20 @@ def ilcp_list_docs_da_batch(index: ILCPIndex, da: jnp.ndarray, lo, hi, max_df: i
 
 
 def ilcp_list_docs_da_planned(index: ILCPIndex, da: jnp.ndarray, lo, hi,
-                              max_df: int, *, use_kernel: bool | None = None,
+                              max_df: int, *, use_kernel: bool = False,
                               block_q: int = 128, interpret: bool | None = None):
     """Sada-I-D listing written batch-first for the serving executor.
 
     Same integers as ``ilcp_list_docs_da_batch`` — documents in discovery
     order, bit-identical across paths.
 
-    ``use_kernel`` selects the execution path:
-      * ``None``  — auto: the fused Pallas kernel on TPU, XLA elsewhere;
-      * ``True``  — force the fused kernel (``repro.kernels.ilcp_list``;
-        one ``pallas_call`` for the whole batched recursion, interpret mode
-        off-TPU unless ``interpret`` says otherwise);
-      * ``False`` — force the XLA vmap'd while_loop path.
+    ``use_kernel`` selects the execution path (the serving layer decides it
+    once, at build time):
+      * ``True``  — the fused kernel (``repro.kernels.ilcp_list``; one
+        ``pallas_call`` for the whole batched recursion, interpreted when
+        the program is lowered for CPU unless ``interpret`` says otherwise);
+      * ``False`` — the XLA vmap'd while_loop path.
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
         from repro.kernels.ops import ilcp_list
 

@@ -153,7 +153,7 @@ def tfidf_topk_batch(
     )(ranges_batch, term_valid_batch, as_i32(dfs_batch))
 
 
-def term_ranges_batch(csa: CSA, patterns, lengths, *, use_kernel: bool | None = False):
+def term_ranges_batch(csa: CSA, patterns, lengths, *, use_kernel: bool = False):
     """Fused multi-term range finding for padded query batches.
 
     patterns: int32[Q, T, max_m] (term-padded, query-padded); lengths:

@@ -33,23 +33,6 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions: the top-level binding (with
-    ``check_vma``) landed after 0.4.x; older releases expose it as
-    jax.experimental.shard_map.shard_map with the ``check_rep`` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshAxes:
     """Role assignment of mesh axes: ``dp`` (tuple, possibly hierarchical),
@@ -249,7 +232,12 @@ def make_docs_mesh(n_shards: int):
             f"n_shards={n_shards} exceeds available devices ({avail}); "
             "set --xla_force_host_platform_device_count"
         )
-    return jax.make_mesh((n_shards,), (DOCS_AXIS,))
+    # Auto axes: the sharded programs steer placement with
+    # with_sharding_constraint, which refuses Explicit axes (the
+    # jax.make_mesh default since JAX 0.7)
+    return jax.make_mesh(
+        (n_shards,), (DOCS_AXIS,), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 def docs_mesh_size(mesh) -> int:

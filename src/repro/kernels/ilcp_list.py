@@ -1,27 +1,23 @@
 """Pallas TPU kernel: fused ILCP document listing — one launch per batch.
 
-The Fig-1 recursion (paper Section 3.3) is the listing hot path: the
-serving executor used to run it as a vmap'd ``lax.while_loop`` issuing one
-XLA ``rmq_query`` gather chain per popped interval, with the dedup bitmap
-``V`` and result buffer living in HBM between iterations.  This kernel runs
-the ENTIRE recursion — bounded explicit stack, leftmost-min sparse-table
-RMQ, run→position resolution, document lookup, distinct-doc dedup up to
-``max_df`` — inside ONE ``pallas_call`` per padded batch:
+The Fig-1 recursion (paper Section 3.3) is the listing hot path.  This
+kernel runs the ENTIRE recursion — bounded explicit stack, leftmost-min
+sparse-table RMQ, run->position resolution, document lookup, distinct-doc
+dedup up to ``max_df`` — inside ONE ``pallas_call`` per padded batch:
 
-  * the flattened RMQ table (the rmq kernel's flattening trick), the run
-    head values ``vilcp``, the run boundaries and the document array stay
-    VMEM-resident across the whole recursion;
-  * the query batch streams through the grid in ``block_q`` tiles;
-  * the per-query interval stack and the bit-packed ``V`` marker live in
-    VMEM scratch (re-seeded at every grid step — scratch persists across
-    grid steps on TPU);
-  * the recursion itself is flattened into a per-query POP/SCAN state
-    machine so the whole tile advances in lockstep through a single
-    ``lax.while_loop``: an iteration either pops an interval and resolves
-    its leftmost-min run (POP), or visits one DA position of the current
-    run (SCAN).  A query's trajectory — pop order, push filters,
-    truncation — replays ``ilcp_list_docs`` exactly, so the reported
-    documents are BIT-identical in discovery order, not just as sets.
+  * the flattened RMQ table, the run head values ``vilcp``, the run
+    boundaries and the document array stay VMEM-resident across the whole
+    recursion, each as one int32 ``(rows, 128)`` table
+    (``repro.kernels.rows``: a lookup is a dynamic row load plus a lane
+    select — Mosaic has no VMEM vector gather);
+  * the query batch streams through the grid in ``block_q`` tiles; each
+    query's bounds come from SMEM and its count goes back to SMEM;
+  * inside one grid step the queries of the tile run one after another as
+    scalar loops that replay ``ilcp_list_docs`` exactly: the interval stack
+    lives in SMEM scratch, the bit-packed ``V`` marker in VMEM scratch
+    (cleared per query), and the query's document row is carried as one
+    vector and stored once.  Documents come out in discovery order,
+    bit-identical to the vmap'd while_loop path and the lockstep oracle.
 
 Callers resolve the query bounds to run indices (``lo_run``/``hi_run``)
 up front with one ``searchsorted`` over the run starts — the same
@@ -40,6 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backward_search import tile_rows
+from repro.kernels.rows import as_rows, load_row, lookup, pick, rows_shape
+
 #: stack capacity / pop budget as functions of max_df — shared with the
 #: while_loop reference (``ilcp_list_docs``) so trajectories stay aligned.
 def stack_cap(max_df: int) -> int:
@@ -50,18 +49,10 @@ def pop_cap(max_df: int) -> int:
     return 2 * max_df + 8
 
 
-def lockstep_iteration_cap(max_df: int) -> int:
-    """Safety ceiling on lockstep iterations per tile.  Each pop costs one
-    iteration (<= pop_cap) and each visited DA position one more; a visited
-    position either reports a new document (<= max_df) or aborts its pop
-    (<= pop_cap), so the trajectory of any single query is bounded by
-    ``pop_cap + max_df + pop_cap`` iterations plus the final retire step.
-    The loop normally exits far earlier on the all-done predicate."""
-    return 5 * max_df + 36
-
-
-_POP = 0
-_SCAN = 1
+def v_rows_shape(d: int) -> tuple[int, int]:
+    """(rows, 128) shape of the bit-packed distinct-document marker V:
+    ceil(d / 32) int32 words."""
+    return rows_shape(-(-max(d, 1) // 32))
 
 
 def _ilcp_list_kernel(
@@ -69,126 +60,107 @@ def _ilcp_list_kernel(
     docs_ref, cnt_ref, stka_ref, stkb_ref, v_ref, *,
     levels: int, rho: int, n: int, d: int, max_df: int,
 ):
-    lo = lo_ref[...]             # int32[block_q] SA-range starts
-    hi = hi_ref[...]             # int32[block_q] SA-range ends (exclusive)
-    lo_run = lor_ref[...]        # int32[block_q] run of lo
-    hi_run = hir_ref[...]        # int32[block_q] run of hi - 1
-    table = table_ref[...]       # int32[levels * rho] flattened RMQ table
-    vilcp = vilcp_ref[...]       # int32[rho] run head values
-    rs = rs_ref[...]             # int32[rho + 1] run boundaries (last = n)
-    da = da_ref[...]             # int32[n] document array
-
-    bq = lo.shape[0]
-    rows = jnp.arange(bq, dtype=jnp.int32)
+    bq = docs_ref.shape[0]
     cap = stack_cap(max_df)
-    iter_cap = pop_cap(max_df)
-    vw = v_ref.shape[1]
-
-    # scratch is persistent across grid steps: re-seed stack + V every step
-    stka_ref[...] = jnp.zeros((bq, cap), jnp.int32).at[:, 0].set(lo_run)
-    stkb_ref[...] = jnp.zeros((bq, cap), jnp.int32).at[:, 0].set(hi_run)
-    v_ref[...] = jnp.zeros((bq, vw), jnp.uint32)
-    docs_ref[...] = jnp.full((bq, max_df), -1, jnp.int32)
+    max_pops = pop_cap(max_df)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, max_df), 1)
 
     def rmq(a, b):
-        # leftmost argmin of vilcp[a..b] — the rmq kernel's flattened gather
+        # leftmost argmin of vilcp[a..b] over the flattened sparse table;
+        # k = min(floor(lg(span)), levels - 1) without a scalar clz
         span = jnp.maximum(b - a + 1, 1)
-        k = jnp.clip(31 - jax.lax.clz(span), 0, levels - 1)
+        k = jnp.int32(0)
+        for i in range(1, levels):
+            k = k + (span >= (1 << i)).astype(jnp.int32)
         right = jnp.maximum(b - (jnp.int32(1) << k) + 1, a)
-        ia = table[k * rho + a]
-        ib = table[k * rho + right]
-        va = vilcp[ia]
-        vb = vilcp[ib]
+        ia = lookup(table_ref, k * rho + a)
+        ib = lookup(table_ref, k * rho + right)
+        va = lookup(vilcp_ref, ia)
+        vb = lookup(vilcp_ref, ib)
         pick_b = (vb < va) | ((vb == va) & (ib < ia))
         return jnp.where(pick_b, ib, ia)
 
-    def cond(c):
-        it, done, *_ = c
-        return jnp.any(~done) & (it < lockstep_iteration_cap(max_df))
+    def query(q, carry):
+        lo = lo_ref[0, q]
+        hi = hi_ref[0, q]
+        stka_ref[0] = lor_ref[0, q]
+        stkb_ref[0] = hir_ref[0, q]
 
-    def body(c):
-        it, done, mode, a, b, i_run, k, j, sp, cnt, pops = c
+        @pl.when(lo < hi)
+        def _():
+            v_ref[...] = jnp.zeros(v_ref.shape, jnp.int32)
 
-        # -- POP: take the top interval, resolve its leftmost-min run -------
-        in_pop = ~done & (mode == _POP)
-        can_pop = in_pop & (sp > 0) & (cnt < max_df) & (pops < iter_cap)
-        done = done | (in_pop & ~can_pop)
+        def more_pops(s):
+            sp, cnt, pops, _ = s
+            return (sp > 0) & (cnt < max_df) & (pops < max_pops)
 
-        sa = stka_ref[...]
-        sb = stkb_ref[...]
-        top = jnp.maximum(sp - 1, 0)
-        a = jnp.where(can_pop, sa[rows, top], a)
-        b = jnp.where(can_pop, sb[rows, top], b)
-        sp = jnp.where(can_pop, sp - 1, sp)
-        pops = jnp.where(can_pop, pops + 1, pops)
+        def pop(s):
+            sp, cnt, pops, docs = s
+            sp = sp - 1
+            a = stka_ref[sp]
+            b = stkb_ref[sp]
+            valid = (a <= b) & (lo < hi)
+            r = rmq(jnp.clip(a, 0, rho - 1), jnp.clip(b, 0, rho - 1))
+            k0 = jnp.maximum(lo, lookup(rs_ref, jnp.clip(r, 0, rho - 1)))
+            j = jnp.minimum(hi, lookup(rs_ref, jnp.clip(r + 1, 0, rho)))
+            j = jnp.where(valid, j, k0)          # an invalid pop scans nothing
 
-        valid = can_pop & (a <= b) & (lo < hi)
-        ca = jnp.clip(a, 0, rho - 1)
-        r = rmq(ca, jnp.clip(b, 0, rho - 1))
-        i_run = jnp.where(valid, r, i_run)
-        k = jnp.where(valid, jnp.maximum(lo, rs[jnp.clip(r, 0, rho - 1)]), k)
-        j = jnp.where(valid, jnp.minimum(hi, rs[jnp.clip(r + 1, 0, rho)]), j)
-        mode = jnp.where(valid, _SCAN, mode)
+            def more_scan(c):
+                k, cnt, aborted, _ = c
+                return (k < j) & (aborted == 0) & (cnt < max_df)
 
-        # -- SCAN: visit one DA position of the current run -----------------
-        # (a freshly popped query scans its first position this iteration)
-        scanning = ~done & (mode == _SCAN)
-        proc = scanning & (k < j) & (cnt < max_df)
-        g = da[jnp.clip(k, 0, n - 1)]
-        gc = jnp.clip(g, 0, max(d - 1, 0))
-        w = gc >> 5
-        bit = jnp.uint32(1) << (gc & 31).astype(jnp.uint32)
-        V = v_ref[...]
-        vword = V[rows, w]
-        seen = (vword & bit) > 0
-        rep = proc & ~seen
-        v_ref[...] = V.at[rows, w].set(jnp.where(proc, vword | bit, vword))
-        docs = docs_ref[...]
-        slot = jnp.minimum(cnt, max_df - 1)
-        docs_ref[...] = docs.at[rows, slot].set(
-            jnp.where(rep, g, docs[rows, slot])
+            def visit(c):
+                # one DA position of the run: report a new document, or
+                # abort the whole subrange on a repeat (Lemma 3)
+                k, cnt, _, docs = c
+                g = lookup(da_ref, jnp.clip(k, 0, n - 1))
+                gc = jnp.clip(g, 0, max(d - 1, 0))
+                vrow, hit = load_row(v_ref, gc >> 5)
+                bit = jnp.int32(1) << (gc & 31)
+                seen = pick(vrow & bit, hit) != 0
+                v_ref[pl.ds(gc >> 12, 1), :] = jnp.where(hit, vrow | bit, vrow)
+                docs = jnp.where((slots == cnt) & ~seen, g, docs)
+                return (k + 1, jnp.where(seen, cnt, cnt + 1),
+                        seen.astype(jnp.int32), docs)
+
+            _, cnt, aborted, docs = jax.lax.while_loop(
+                more_scan, visit, (k0, cnt, jnp.int32(0), docs)
+            )
+            # push right subrange first, then left (left popped first —
+            # Lemma 3 with the leftmost RMQ); aborts kill the whole subrange
+            push = valid & (aborted == 0)
+            do1 = push & (r + 1 <= b) & (sp < cap)
+
+            @pl.when(do1)
+            def _():
+                stka_ref[sp] = r + 1
+                stkb_ref[sp] = b
+
+            sp = sp + do1.astype(jnp.int32)
+            do2 = push & (a <= r - 1) & (sp < cap)
+
+            @pl.when(do2)
+            def _():
+                stka_ref[sp] = a
+                stkb_ref[sp] = r - 1
+
+            return sp + do2.astype(jnp.int32), cnt, pops + 1, docs
+
+        _, cnt, _, docs = jax.lax.while_loop(
+            more_pops, pop,
+            (jnp.int32(1), jnp.int32(0), jnp.int32(0),
+             jnp.full((1, max_df), -1, jnp.int32)),
         )
-        cnt = jnp.where(rep, cnt + 1, cnt)
-        k = jnp.where(proc, k + 1, k)
-        aborted = proc & seen
-        ended = scanning & (aborted | (k >= j) | (cnt >= max_df))
+        docs_ref[pl.ds(q, 1), :] = docs
+        cnt_ref[0, q] = cnt
+        return carry
 
-        # -- push right subrange first, then left (left popped first —
-        #    Lemma 3 with the leftmost RMQ); aborts kill the whole subrange
-        push = ended & ~aborted
-        slot1 = jnp.minimum(sp, cap - 1)
-        do1 = push & (i_run + 1 <= b) & (sp < cap)
-        sa = sa.at[rows, slot1].set(jnp.where(do1, i_run + 1, sa[rows, slot1]))
-        sb = sb.at[rows, slot1].set(jnp.where(do1, b, sb[rows, slot1]))
-        sp = jnp.where(do1, sp + 1, sp)
-        slot2 = jnp.minimum(sp, cap - 1)
-        do2 = push & (a <= i_run - 1) & (sp < cap)
-        sa = sa.at[rows, slot2].set(jnp.where(do2, a, sa[rows, slot2]))
-        sb = sb.at[rows, slot2].set(jnp.where(do2, i_run - 1, sb[rows, slot2]))
-        sp = jnp.where(do2, sp + 1, sp)
-        stka_ref[...] = sa
-        stkb_ref[...] = sb
-        mode = jnp.where(ended, _POP, mode)
-
-        return (it + 1, done, mode, a, b, i_run, k, j, sp, cnt, pops)
-
-    zeros = jnp.zeros(bq, jnp.int32)
-    init = (
-        jnp.int32(0),                    # lockstep iteration counter
-        jnp.zeros(bq, jnp.bool_),        # done
-        zeros,                           # mode (all start popping)
-        zeros, zeros,                    # (a, b) current interval
-        zeros, zeros, zeros,             # i_run, k, j
-        jnp.ones(bq, jnp.int32),         # sp (root interval seeded)
-        zeros,                           # cnt
-        zeros,                           # pops
-    )
-    final = jax.lax.while_loop(cond, body, init)
-    cnt_ref[...] = final[9]
+    jax.lax.fori_loop(0, bq, query, 0)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("d", "max_df", "block_q", "interpret")
+    jax.jit,
+    static_argnames=("d", "max_df", "block_q", "interpret"),
 )
 def ilcp_list_pallas(
     vilcp: jnp.ndarray,       # int32[rho] run head values (RMQ values)
@@ -214,46 +186,42 @@ def ilcp_list_pallas(
     levels, rho = table.shape
     n = da.shape[0]
     B = lo.shape[0]
-    bq = min(block_q, max(B, 1))
-    bpad = -(-B // bq) * bq
+    bq = tile_rows(batch=B, block_q=block_q)
+    tiles = -(-B // bq)
+    bpad = tiles * bq
 
-    def pad(x, fill):
-        return jnp.full(bpad, fill, jnp.int32).at[:B].set(x)
+    def per_query(x, fill):
+        return jnp.full(bpad, fill, jnp.int32).at[:B].set(x).reshape(tiles, 1, bq)
 
-    vw = -(-max(d, 1) // 32)
     kernel = functools.partial(
         _ilcp_list_kernel,
         levels=levels, rho=rho, n=n, d=d, max_df=max_df,
     )
+    query_spec = pl.BlockSpec(
+        (None, 1, bq), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
+    )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     docs, cnt = pl.pallas_call(
         kernel,
-        grid=(bpad // bq,),
-        in_specs=[
-            pl.BlockSpec((bq,), lambda i: (i,)),
-            pl.BlockSpec((bq,), lambda i: (i,)),
-            pl.BlockSpec((bq,), lambda i: (i,)),
-            pl.BlockSpec((bq,), lambda i: (i,)),
-            pl.BlockSpec((levels * rho,), lambda i: (0,)),
-            pl.BlockSpec((rho,), lambda i: (0,)),
-            pl.BlockSpec(run_starts.shape, lambda i: (0,)),
-            pl.BlockSpec(da.shape, lambda i: (0,)),
-        ],
+        grid=(tiles,),
+        in_specs=[query_spec] * 4 + [vmem] * 4,
         out_specs=[
             pl.BlockSpec((bq, max_df), lambda i: (i, 0)),
-            pl.BlockSpec((bq,), lambda i: (i,)),
+            query_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bpad, max_df), jnp.int32),
-            jax.ShapeDtypeStruct((bpad,), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 1, bq), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, stack_cap(max_df)), jnp.int32),   # stack a
-            pltpu.VMEM((bq, stack_cap(max_df)), jnp.int32),   # stack b
-            pltpu.VMEM((bq, vw), jnp.uint32),                 # V (bit-packed)
+            pltpu.SMEM((stack_cap(max_df),), jnp.int32),     # stack a
+            pltpu.SMEM((stack_cap(max_df),), jnp.int32),     # stack b
+            pltpu.VMEM(v_rows_shape(d), jnp.int32),          # V (bit-packed)
         ],
         interpret=interpret,
     )(
-        pad(lo, 0), pad(hi, 0), pad(lo_run, 0), pad(hi_run, -1),
-        table.reshape(-1), vilcp, run_starts, da,
+        per_query(lo, 0), per_query(hi, 0),
+        per_query(lo_run, 0), per_query(hi_run, -1),
+        as_rows(table), as_rows(vilcp), as_rows(run_starts), as_rows(da),
     )
-    return docs[:B], cnt[:B]
+    return docs[:B], cnt.reshape(bpad)[:B]

@@ -1,14 +1,28 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: Pallas interpret mode on CPU (this
-container), compiled Mosaic on TPU.  Every wrapper falls back to the pure
-jnp reference when the input shapes don't meet the kernel's tiling
-constraints — the framework never fails on odd shapes, it just takes the
-XLA path.
+``interpret=None`` (the default) lets the lowering platform decide: the
+kernel is compiled by Mosaic when the program is lowered for TPU and runs
+in the Pallas interpreter when it is lowered for CPU
+(``jax.lax.platform_dependent``).  One traced program is therefore right on
+both, and an AOT compile for a described TPU exercises the real kernel.
+
+The serving wrappers never swap a kernel for its reference.  Whether the
+fused kernels run at all is decided once, at build time, from the platform
+and the VMEM budgets below (``backward_search_fits`` / ``ilcp_list_fits``
+feed ``RetrievalService.build``), so the service's ``use_search_kernel`` /
+``use_list_kernel`` flags say what runs.  Only degenerate shapes with a
+closed-form answer skip the launch.
+
+VMEM layout, as compiled for v5e: the resident tables are whole-array VMEM
+operands that XLA places in VMEM once per launch (one copy); the per-tile
+blocks are double-buffered by the Pallas pipeline; the listing kernel's
+stacks live in SMEM.  The ``*_block_meta`` helpers list the VMEM entries
+with their buffer counts.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -16,36 +30,39 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.backward_search import backward_search_pallas
+from repro.kernels.backward_search import backward_search_pallas, tile_rows
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ilcp_list import ilcp_list_pallas, stack_cap
+from repro.kernels.ilcp_list import ilcp_list_pallas, v_rows_shape
 from repro.kernels.rank import rank_pallas
 from repro.kernels.rmq import rmq_pallas
+from repro.kernels.rows import rows_shape
 
-#: per-core VMEM the backward-search kernel may claim for the wavelet
-#: matrix; larger indexes take the XLA pair-descent path instead (sharding
-#: the index over cores is the ROADMAP's per-shard serving follow-up).
+#: VMEM the backward-search kernel may keep resident for one wavelet
+#: matrix; a larger index is served by the XLA pair descent (chosen at
+#: build time), or sharded over a docs mesh so each shard fits.
 BACKWARD_SEARCH_VMEM_BUDGET = 12 * 2**20
 
-#: per-core VMEM the fused listing kernel may claim — resident tables
-#: (flattened RMQ table + vilcp + run boundaries + document array) PLUS the
-#: per-tile scratch (interval stacks + bit-packed V); past it the executor
-#: takes the XLA while_loop path, and sharding restores the kernel exactly
-#: as it does for backward search (each shard's tables are ~1/S the size).
+#: VMEM the fused listing kernel may keep resident: the RMQ table, vilcp,
+#: the run boundaries, the document array and the bit-packed V marker.
 ILCP_LIST_VMEM_BUDGET = 12 * 2**20
 
 
-def backward_search_resident_bytes(words, ones_prefix, zcount, base) -> int:
-    """VMEM the fused kernel keeps resident across the whole search: the
-    flattened wavelet levels plus the zcount/base tables (every element is
-    4 bytes wide — uint32 words, int32 tables).
+def _rows_bytes(size: int) -> int:
+    return math.prod(rows_shape(size)) * 4
 
-    Single source of truth for the budget decision: the wrapper below
-    compares this against ``BACKWARD_SEARCH_VMEM_BUDGET`` before launching,
-    and ``repro.analysis`` re-derives the same number at audit time to
-    prove the fallback engages at lowering time."""
-    return int(words.size + ones_prefix.size + zcount.size + base.size) * 4
+
+def backward_search_resident_bytes(words, ones_prefix) -> int:
+    """VMEM the fused kernel keeps resident across the whole search: the
+    flattened wavelet levels as two int32 (rows, 128) tables."""
+    return _rows_bytes(int(words.size)) + _rows_bytes(int(ones_prefix.size))
+
+
+def backward_search_fits(words, ones_prefix) -> bool:
+    """Whether the wavelet matrix fits ``BACKWARD_SEARCH_VMEM_BUDGET`` —
+    the build-time half of the kernel selection."""
+    return (backward_search_resident_bytes(words, ones_prefix)
+            <= BACKWARD_SEARCH_VMEM_BUDGET)
 
 
 def shards_to_fit(resident_bytes: int,
@@ -55,9 +72,8 @@ def shards_to_fit(resident_bytes: int,
     balanced contiguous document split of ``doc_shard_bounds`` (each
     shard's matrix is ~1/S of the whole: same levels, 1/S of the text).
 
-    Sizing hint for ``RetrievalService.build(mesh=...)`` — the serving
-    layer restores the fused kernel path for over-budget indexes by
-    sharding; see docs/SHARDING.md."""
+    Sizing hint for ``RetrievalService.build(mesh=...)`` — see
+    docs/SHARDING.md."""
     if budget is None:
         budget = BACKWARD_SEARCH_VMEM_BUDGET
     if budget <= 0:
@@ -65,48 +81,42 @@ def shards_to_fit(resident_bytes: int,
     return max(1, -(-resident_bytes // budget))
 
 
-def backward_search_block_meta(words, ones_prefix, zcount, base,
-                               batch: int, max_m: int, *,
-                               block_q: int = 256) -> list:
-    """Per-grid-step block layout of the fused kernel as (shape, dtype)
-    pairs, mirroring the BlockSpecs in ``backward_search_pallas``.
-
-    Exported for the static VMEM estimator in ``repro.analysis.contracts``:
-    summing these blocks bounds what one grid step holds in VMEM, so the
-    budget check can run on a traced jaxpr instead of live hardware."""
-    levels, stride = words.shape
-    bq = min(block_q, max(batch, 1))
+def backward_search_block_meta(words, ones_prefix, batch: int, max_m: int,
+                               *, block_q: int = 256) -> list:
+    """VMEM the fused kernel claims, as (shape, dtype, buffers) entries
+    mirroring ``backward_search_pallas``: the double-buffered pattern tile
+    and the two resident tables.  Lengths, zcount, base and the results
+    live in SMEM."""
     return [
-        ((bq, max_m), "int32"),            # pattern tile
-        ((bq,), "int32"),                  # lengths tile
-        ((levels * stride,), "uint32"),    # flattened words (resident)
-        ((levels * stride,), "int32"),     # flattened ones_prefix (resident)
-        (tuple(zcount.shape), "int32"),    # zcount (resident)
-        (tuple(base.shape), "int32"),      # base = counts - sym_starts
-        ((bq,), "int32"),                  # lo out
-        ((bq,), "int32"),                  # hi out
+        ((tile_rows(batch=batch, block_q=block_q), max_m), "int32", 2),   # pattern tile
+        (rows_shape(int(words.size)), "int32", 1),          # words
+        (rows_shape(int(ones_prefix.size)), "int32", 1),    # ones_prefix
     ]
 
 
 def block_meta_bytes(meta) -> int:
-    """Total bytes of a block layout from ``backward_search_block_meta``."""
+    """Total VMEM bytes of a ``*_block_meta`` layout."""
     return sum(
-        int(math.prod(shape)) * np.dtype(dtype).itemsize
-        for shape, dtype in meta
+        int(math.prod(shape)) * np.dtype(dtype).itemsize * buffers
+        for shape, dtype, buffers in meta
     )
 
 
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+def _launch(kernel, *args, interpret, **kw):
+    """Run a jitted Pallas kernel.  ``interpret=None`` compiles it with
+    Mosaic for TPU and interprets it for CPU, per lowering platform."""
+    if interpret is not None:
+        return kernel(*args, interpret=interpret, **kw)
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=functools.partial(kernel, interpret=True, **kw),
+        tpu=functools.partial(kernel, interpret=False, **kw),
+    )
 
 
 def rank(words, ones_prefix, idx, *, block_q=1024, interpret=None):
-    return rank_pallas(
-        words, ones_prefix, idx, block_q=block_q,
-        interpret=_auto_interpret(interpret),
-    )
+    return _launch(rank_pallas, words, ones_prefix, idx,
+                   interpret=interpret, block_q=block_q)
 
 
 def backward_search(words, ones_prefix, zcount, base, patterns, lengths, *,
@@ -115,85 +125,70 @@ def backward_search(words, ones_prefix, zcount, base, patterns, lengths, *,
 
     Takes natural left-to-right padded patterns; the right-to-left
     processing order the kernel wants is materialised here with one gather.
-    Odd shapes (empty batch, zero-width patterns, degenerate alphabet) and
-    wavelet matrices past the VMEM budget fall back to the pure-jnp oracle
-    — the framework never fails on shape, it just takes the XLA path.
+    Degenerate shapes have closed forms and launch nothing: an empty batch,
+    zero-width patterns (every row is the empty pattern: (0, n)), and an
+    empty alphabet (every non-empty pattern is out of alphabet).
     """
     patterns = jnp.asarray(patterns, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     B, max_m = patterns.shape
+    if B == 0 or max_m == 0:
+        return jnp.zeros(B, jnp.int32), jnp.full(B, n, jnp.int32)
     j = jnp.clip(
         lengths[:, None] - 1 - jnp.arange(max_m, dtype=jnp.int32)[None, :],
-        0, max(max_m - 1, 0),
+        0, max_m - 1,
     )
-    rev = jnp.take_along_axis(patterns, j, axis=1) if max_m else patterns
-    resident_bytes = backward_search_resident_bytes(
-        words, ones_prefix, zcount, base
-    )
-    if (
-        B == 0 or max_m == 0 or base.shape[0] == 0
-        or resident_bytes > BACKWARD_SEARCH_VMEM_BUDGET
-    ):
-        return ref.backward_search_ref(
-            words, ones_prefix, zcount, base, rev, lengths, n=n, sigma=sigma
-        )
-    return backward_search_pallas(
+    rev = jnp.take_along_axis(patterns, j, axis=1)
+    if base.shape[0] == 0:
+        lo = jnp.where((lengths > 0) & (rev[:, 0] >= 0), n, 0).astype(jnp.int32)
+        return lo, jnp.where(lengths > 0, lo, n).astype(jnp.int32)
+    return _launch(
+        backward_search_pallas,
         words, ones_prefix, zcount, base, rev, lengths,
-        n=n, sigma=sigma, block_q=block_q,
-        interpret=_auto_interpret(interpret),
+        interpret=interpret, n=n, sigma=sigma, block_q=block_q,
     )
 
 
 def rmq(values, table, lo, hi, *, block_q=1024, interpret=None):
-    return rmq_pallas(
-        values, table, lo, hi, block_q=block_q,
-        interpret=_auto_interpret(interpret),
-    )
+    return _launch(rmq_pallas, values, table, lo, hi,
+                   interpret=interpret, block_q=block_q)
 
 
 def ilcp_list_resident_bytes(vilcp, table, run_starts, da) -> int:
     """VMEM the fused listing kernel keeps resident across the recursion:
     the flattened RMQ table, the run head values, the run boundaries and
-    the document array (all int32).  Single source of truth for the budget
-    decision, like ``backward_search_resident_bytes``."""
-    return int(table.size + vilcp.size + run_starts.size + da.size) * 4
+    the document array, each an int32 (rows, 128) table."""
+    return sum(_rows_bytes(int(x.size)) for x in (table, vilcp, run_starts, da))
 
 
-def ilcp_list_scratch_bytes(batch: int, *, d: int, max_df: int,
-                            block_q: int = 128) -> int:
-    """VMEM scratch one grid step of the listing kernel allocates: two
-    int32 interval stacks of ``stack_cap(max_df)`` entries per query plus
-    the bit-packed distinct-document marker (ceil(d/32) uint32 words)."""
-    bq = min(block_q, max(batch, 1))
-    vw = -(-max(d, 1) // 32)
-    return (2 * bq * stack_cap(max_df) + bq * vw) * 4
+def ilcp_list_scratch_bytes(d: int) -> int:
+    """VMEM scratch of the listing kernel: the bit-packed distinct-document
+    marker V (ceil(d / 32) int32 words, padded to (rows, 128)).  The
+    interval stacks are SMEM."""
+    return math.prod(v_rows_shape(d)) * 4
+
+
+def ilcp_list_fits(vilcp, table, run_starts, da, *, d: int) -> bool:
+    """Whether the listing kernel's resident tables plus V fit
+    ``ILCP_LIST_VMEM_BUDGET`` — the build-time half of the selection."""
+    return (ilcp_list_resident_bytes(vilcp, table, run_starts, da)
+            + ilcp_list_scratch_bytes(d)) <= ILCP_LIST_VMEM_BUDGET
 
 
 def ilcp_list_block_meta(vilcp, table, run_starts, da,
                          batch: int, *, d: int, max_df: int,
                          block_q: int = 128) -> list:
-    """Per-grid-step block layout of the fused listing kernel as
-    (shape, dtype) pairs, mirroring the BlockSpecs AND the
-    ``scratch_shapes`` in ``ilcp_list_pallas`` — the scratch entries are
-    what forced the analysis estimator to learn about scratch operands.
-    Summing via ``block_meta_bytes`` bounds one grid step's VMEM."""
-    levels, rho = table.shape
-    bq = min(block_q, max(batch, 1))
-    vw = -(-max(d, 1) // 32)
+    """VMEM the fused listing kernel claims, as (shape, dtype, buffers)
+    entries mirroring ``ilcp_list_pallas``: the double-buffered docs tile,
+    the four resident tables and the V scratch.  Query bounds, counts and
+    the interval stacks live in SMEM."""
     return [
-        ((bq,), "int32"),                  # lo tile
-        ((bq,), "int32"),                  # hi tile
-        ((bq,), "int32"),                  # lo_run tile
-        ((bq,), "int32"),                  # hi_run tile
-        ((levels * rho,), "int32"),        # flattened RMQ table (resident)
-        ((rho,), "int32"),                 # vilcp (resident)
-        (tuple(run_starts.shape), "int32"),  # run boundaries (resident)
-        (tuple(da.shape), "int32"),        # document array (resident)
-        ((bq, max_df), "int32"),           # docs out
-        ((bq,), "int32"),                  # cnt out
-        ((bq, stack_cap(max_df)), "int32"),  # scratch: stack a
-        ((bq, stack_cap(max_df)), "int32"),  # scratch: stack b
-        ((bq, vw), "uint32"),              # scratch: bit-packed V
+        ((tile_rows(batch=batch, block_q=block_q), max_df), "int32", 2),  # docs out tile
+        (rows_shape(int(table.size)), "int32", 1),           # RMQ table
+        (rows_shape(int(vilcp.size)), "int32", 1),           # vilcp
+        (rows_shape(int(run_starts.size)), "int32", 1),      # run boundaries
+        (rows_shape(int(da.size)), "int32", 1),              # document array
+        (v_rows_shape(d), "int32", 1),                       # scratch: V
     ]
 
 
@@ -214,41 +209,27 @@ def ilcp_list(vilcp, table, run_starts, da, lo, hi, *,
 
     Takes SA ranges; the run indices of the range endpoints the kernel
     wants are materialised here with one searchsorted per boundary — the
-    backward-search wrapper's pattern-reversal move.  Odd shapes (empty
-    batch, zero ``max_df``) and index stacks past the VMEM budget fall
-    back to the pure-jnp lockstep oracle — the framework never fails on
-    shape, it just takes the XLA path.
+    backward-search wrapper's pattern-reversal move.  Degenerate shapes
+    (empty batch, zero ``max_df``, no documents) have a closed-form answer
+    (no documents) and launch nothing.
     """
     lo = jnp.asarray(lo, jnp.int32)
     hi = jnp.asarray(hi, jnp.int32)
     B = lo.shape[0]
     if B == 0 or max_df <= 0 or d <= 0:
-        # degenerate shapes have a closed-form answer (no documents); the
-        # (B, 0) docs buffer can't even be scatter-indexed by the oracle
         return (jnp.full((B, max(max_df, 0)), -1, jnp.int32),
                 jnp.zeros((B,), jnp.int32))
-    lo_run = runs_of(run_starts, lo)
-    hi_run = runs_of(run_starts, hi - 1)
-    vmem_bytes = block_meta_bytes(ilcp_list_block_meta(
-        vilcp, table, run_starts, da, B, d=d, max_df=max_df, block_q=block_q
-    ))
-    if vmem_bytes > ILCP_LIST_VMEM_BUDGET:
-        return ref.ilcp_list_ref(
-            vilcp, table, run_starts, da, lo, hi, lo_run, hi_run,
-            d=d, max_df=max_df,
-        )
-    return ilcp_list_pallas(
-        vilcp, table, run_starts, da, lo, hi, lo_run, hi_run,
-        d=d, max_df=max_df, block_q=block_q,
-        interpret=_auto_interpret(interpret),
+    return _launch(
+        ilcp_list_pallas,
+        vilcp, table, run_starts, da, lo, hi,
+        runs_of(run_starts, lo), runs_of(run_starts, hi - 1),
+        interpret=interpret, d=d, max_df=max_df, block_q=block_q,
     )
 
 
 def embedding_bag(table, padded_idx, *, mode="sum", block_b=128, interpret=None):
-    return embedding_bag_pallas(
-        table, padded_idx, mode=mode, block_b=block_b,
-        interpret=_auto_interpret(interpret),
-    )
+    return _launch(embedding_bag_pallas, table, padded_idx,
+                   interpret=interpret, mode=mode, block_b=block_b)
 
 
 def flash_attention(
@@ -259,7 +240,5 @@ def flash_attention(
     bk = min(block_k, Skv)
     if Sq % bq or Skv % bk:
         return ref.flash_attention_ref(q, k, v, causal=causal)
-    return flash_attention_pallas(
-        q, k, v, causal=causal, block_q=bq, block_k=bk,
-        interpret=_auto_interpret(interpret),
-    )
+    return _launch(flash_attention_pallas, q, k, v,
+                   interpret=interpret, causal=causal, block_q=bq, block_k=bk)

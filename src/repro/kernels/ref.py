@@ -101,6 +101,17 @@ def rmq_ref(values: jnp.ndarray, table: jnp.ndarray, lo: jnp.ndarray, hi: jnp.nd
     return jnp.where(pick_b, b, a).astype(jnp.int32)
 
 
+def lockstep_iteration_cap(max_df: int) -> int:
+    """Safety ceiling on the lockstep iterations of ``ilcp_list_ref``.
+    Each pop costs one iteration (<= pop_cap) and each visited DA position
+    one more; a visited position either reports a new document (<= max_df)
+    or aborts its pop (<= pop_cap), so the trajectory of any single query
+    is bounded by ``pop_cap + max_df + pop_cap`` iterations plus the final
+    retire step.  The loop normally exits far earlier on the all-done
+    predicate."""
+    return 5 * max_df + 36
+
+
 def ilcp_list_ref(
     vilcp: jnp.ndarray,       # int32[rho] run head values (RMQ values)
     table: jnp.ndarray,       # int32[levels, rho] sparse-table argmins
@@ -128,9 +139,7 @@ def ilcp_list_ref(
     route the popped-interval RMQ through the batched Pallas RMQ kernel
     (``repro.kernels.ops.rmq``); default is the inline two-gather chain.
     """
-    from repro.kernels.ilcp_list import (
-        lockstep_iteration_cap, pop_cap, stack_cap,
-    )
+    from repro.kernels.ilcp_list import pop_cap, stack_cap
 
     levels, rho = table.shape
     n = da.shape[0]

@@ -16,12 +16,20 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # Auto axes: the models steer placement with with_sharding_constraint,
+    # which refuses Explicit axes (the jax.make_mesh default since JAX 0.7)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for smoke tests / examples on this container."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

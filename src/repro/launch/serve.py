@@ -25,6 +25,7 @@ import time
 
 import numpy as np
 
+from repro.common import enable_compile_cache
 from repro.data.collections import (
     generate,
     paperlike_collections,
@@ -50,6 +51,7 @@ def main():
                     help="fault specs, e.g. 'executor_fail:0.1,slow_pdl' "
                          "(see repro.serve.faults.NAMED_FAULTS)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = paperlike_collections()[args.corpus]
     coll = generate(spec)

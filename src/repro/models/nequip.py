@@ -386,10 +386,8 @@ def partitioned_train_step_fn(cfg: NequIPConfig, mesh, axes_all, n_graphs: int,
         e = jax.lax.psum(e_part, axes_all)
         return jnp.mean((e - energy) ** 2)
 
-    from repro.dist.sharding import shard_map_compat
-
     P_ = P
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         loss_local,
         mesh=mesh,
         in_specs=(

@@ -445,10 +445,8 @@ def _moe_ffn_ep(cfg: LMConfig, p, x, capacity_factor: float | None = None):
         aux = jax.lax.pmean(aux, dp + (mdl,))
         return y, aux
 
-    from repro.dist.sharding import shard_map_compat
-
     f_dp = dspec if cfg.ep_fsdp else None
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -62,7 +62,7 @@ def plan_queries(
     occ_df_threshold,          # traced f32 scalar
     forced_engine,             # traced i32 scalar; -1 = auto dispatch
     *,
-    use_kernel: bool | None = None,
+    use_kernel: bool = False,
 ) -> QueryPlan:
     """One fused pass: ranges + df + occ + engine assignment.
 
@@ -70,8 +70,7 @@ def plan_queries(
     ``ENGINE_EMPTY``; executors skip them under masking and the serving
     layer reports them as empty results.  ``use_kernel`` selects the range
     search's execution path: the fused Pallas backward-search kernel (one
-    launch per batch — the TPU hot path) or the XLA pair-descent fallback;
-    ``None`` auto-detects the backend (kernel iff TPU).
+    launch per batch — the TPU hot path) or the XLA pair descent.
     """
     lengths = as_i32(lengths)
     lo, hi = csa_search_planned(

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 
 import numpy as np
 import jax
@@ -77,6 +78,7 @@ from repro.core.sada import build_sada, sada_count_batch
 from repro.core.suffix import Collection, build_suffix_data
 from repro.core.tfidf import term_ranges_batch, tfidf_topk_batch
 from repro.data.collections import normalize_patterns, pad_patterns
+from repro.kernels import ops
 from repro.serve import faults
 from repro.serve.planner import (
     ENGINE_BRUTE,
@@ -220,6 +222,25 @@ def _tfidf_program(
     )
 
 
+def kernel_selection(parts, platform: str) -> tuple[bool, bool]:
+    """(use_search_kernel, use_list_kernel) that ``build`` selects on
+    ``platform`` for an index made of ``parts`` (one per docs shard, each
+    with ``csa`` / ``ilcp`` / ``da``): each fused Pallas kernel on TPU when
+    every part's resident tables fit its VMEM budget, the XLA executor
+    otherwise."""
+    on_tpu = platform == "tpu"
+    search = on_tpu and all(
+        ops.backward_search_fits(p.csa.wm.words, p.csa.wm.ones_prefix)
+        for p in parts
+    )
+    listing = on_tpu and all(
+        ops.ilcp_list_fits(p.ilcp.vilcp, p.ilcp.rmq.table, p.ilcp.run_starts,
+                           p.da, d=p.ilcp.d)
+        for p in parts
+    )
+    return search, listing
+
+
 @dataclasses.dataclass
 class RetrievalService:
     coll: Collection
@@ -230,8 +251,8 @@ class RetrievalService:
     sada: object
     da: object
     occ_df_threshold: float = 4.0     # paper: brute wins when occ/df < ~4
-    use_search_kernel: bool = False   # fused Pallas backward search (TPU path)
-    use_list_kernel: bool = False     # fused Pallas ILCP listing (TPU path)
+    use_search_kernel: bool = False   # fused Pallas backward search runs
+    use_list_kernel: bool = False     # fused Pallas ILCP listing runs
     brute_window: int | None = None   # None = size per bucket from occ stats
     _cache: dict = dataclasses.field(default_factory=dict, repr=False)
     _brute_windows: dict = dataclasses.field(default_factory=dict, repr=False)
@@ -239,6 +260,8 @@ class RetrievalService:
     #: per-structure CRC32s recorded by build-time validation (``repro.
     #: serve.validate``); a load path compares them via verify_fingerprints
     fingerprints: dict = dataclasses.field(default_factory=dict, repr=False)
+    #: host seconds per build stage (suffix arrays, each structure, validate)
+    build_seconds: dict = dataclasses.field(default_factory=dict, repr=False)
 
     # -- construction --------------------------------------------------------
 
@@ -251,6 +274,7 @@ class RetrievalService:
         brute_window: int | None = None,
         validate: bool = True,
         mesh=None,
+        clock=time.perf_counter,
     ):
         if mesh is not None:
             # docs-axis sharded service: contiguous document shards, each
@@ -264,32 +288,50 @@ class RetrievalService:
                 use_list_kernel=use_list_kernel,
                 brute_window=brute_window, validate=validate,
             )
-        data = build_suffix_data(coll)
-        if use_search_kernel is None:
-            # backend auto-detection: the fused backward-search kernel is
-            # the default on TPU; elsewhere the XLA pair descent wins
-            use_search_kernel = jax.default_backend() == "tpu"
-        if use_list_kernel is None:
-            # same auto-detection for the fused ILCP listing kernel
-            use_list_kernel = jax.default_backend() == "tpu"
+        seconds = {}
+        last = clock()
+
+        def stage(name, value):
+            nonlocal last
+            now = clock()
+            seconds[name] = now - last
+            last = now
+            return value
+
+        data = stage("suffix", build_suffix_data(coll))
+        csa = stage("csa", build_csa(data, sample_rate=sample_rate))
+        ilcp = stage("ilcp", build_ilcp(data))
+        pdl_list = stage("pdl_list", build_pdl(
+            data, block_size=block_size, beta=beta, mode="list"
+        ))
+        pdl_topk = stage("pdl_topk", build_pdl(
+            data, block_size=block_size, beta=None, mode="topk"
+        ))
+        sada = stage("sada", build_sada(data, sada_variant))
         svc = cls(
             coll=coll,
-            csa=build_csa(data, sample_rate=sample_rate),
-            ilcp=build_ilcp(data),
-            pdl_list=build_pdl(data, block_size=block_size, beta=beta, mode="list"),
-            pdl_topk=build_pdl(data, block_size=block_size, beta=None, mode="topk"),
-            sada=build_sada(data, sada_variant),
+            csa=csa,
+            ilcp=ilcp,
+            pdl_list=pdl_list,
+            pdl_topk=pdl_topk,
+            sada=sada,
             da=jnp.asarray(data.da),
-            use_search_kernel=use_search_kernel,
-            use_list_kernel=use_list_kernel,
             brute_window=brute_window,
+            build_seconds=seconds,
         )
+        # ``None`` selects from what the build can observe (platform and
+        # VMEM budgets); an explicit flag is obeyed, and an index that does
+        # not fit then fails to compile instead of changing path
+        search_k, list_k = kernel_selection([svc], jax.default_backend())
+        svc.use_search_kernel = (search_k if use_search_kernel is None
+                                 else use_search_kernel)
+        svc.use_list_kernel = list_k if use_list_kernel is None else use_list_kernel
         if validate:
             # structural invariants + checksums: a corrupted index is
             # rejected here, before it can serve wrong answers
             from repro.serve.validate import validate_service
 
-            svc.fingerprints.update(validate_service(svc))
+            svc.fingerprints.update(stage("validate", validate_service(svc)))
         return svc
 
     # -- compile cache -------------------------------------------------------

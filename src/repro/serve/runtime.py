@@ -1,12 +1,16 @@
 """Resilient request-execution runtime over :class:`RetrievalService`.
 
-The batched engine (PR 1-2) fails the way a research script fails: one
-malformed pattern, one over-budget compile, or one slow PDL query takes the
+The batched engine alone fails the way a research script fails: one
+malformed pattern, one failed attempt, or one slow PDL query takes the
 whole batch — and the process — down with it.  This module wraps the
 service in a serving-grade execution layer with one contract:
 
     **every admitted request gets an answer** — possibly degraded, always
     flagged — **within its deadline plus at most one batch interval.**
+
+A program that cannot run at all (a lowering or compile error, a program
+that does not fit the device) is not a failed attempt: it propagates, so a
+broken device path is never hidden behind degraded host answers.
 
 Architecture
 ------------
@@ -17,8 +21,8 @@ Architecture
   contract of ``serve.retrieval``) and *shrunk* when the steady-state
   latency estimate for that (kind, bucket) would blow the earliest
   deadline's slack.
-* **Retry with backoff**: a failed execution attempt (device error,
-  injected fault, poisoned payload) is retried up to
+* **Retry with backoff**: a transient execution failure (injected fault,
+  poisoned payload) is retried up to
   ``RuntimeConfig.max_retries`` times with exponential backoff.
 * **Circuit breaker per (kind, bucket)**: attempts exhausted count as one
   breaker failure; ``breaker_threshold`` consecutive failures trip the
@@ -44,7 +48,10 @@ Error taxonomy (see :mod:`repro.errors`)
   exception.
 * ``TransientExecutionError`` (incl. ``FaultInjectedError``,
   ``PoisonedResultError``) — a single attempt failed; consumed internally
-  by the retry/breaker machinery, never surfaced to callers.
+  by the retry/breaker machinery, never surfaced to callers.  It is the
+  only exception the ladder consumes: a lowering or compile error, or a
+  program that does not fit the device, propagates out of ``step`` /
+  ``serve`` instead of turning into a degraded answer.
 * ``DeadlineExceeded`` — never raised to callers by this runtime; it is
   converted into an answer with ``deadline_missed=True`` (degraded-empty
   if the deadline passed while still queued, late-but-real if execution
@@ -83,6 +90,7 @@ from repro.errors import (
     InvalidQueryError,
     PoisonedResultError,
     QueueFullError,
+    TransientExecutionError,
 )
 from repro.serve.retrieval import MAX_PATTERN_LEN
 
@@ -250,7 +258,7 @@ class ServeRuntime:
     def submit(self, kind: str, payload, *, deadline_s: float | None = None) -> int:
         """Admit one request; returns its id.  Raises InvalidQueryError for
         structurally bad payloads and QueueFullError at capacity — the only
-        two exceptions this runtime surfaces."""
+        two exceptions admission raises."""
         if kind not in KINDS:
             self.metrics.invalid += 1
             raise InvalidQueryError(f"unknown endpoint kind {kind!r}")
@@ -403,7 +411,7 @@ class ServeRuntime:
                     results = self._call(kind, reqs, "full")
                     self.breaker.record_success(key)
                     break
-                except Exception:
+                except TransientExecutionError:
                     retries += 1
                     m.retries += 1
                     if attempt < cfg.max_retries:
@@ -421,7 +429,7 @@ class ServeRuntime:
                     results = self._call(kind, reqs, path)
                     reason = f"{cause}:{path}"
                     break
-                except Exception:
+                except TransientExecutionError:
                     continue
             else:
                 path = "empty"

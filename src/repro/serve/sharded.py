@@ -2,10 +2,10 @@
 
 The single-device ``RetrievalService`` holds the whole index pytree in one
 memory domain; once the CSA wavelet matrix outgrows
-``BACKWARD_SEARCH_VMEM_BUDGET`` the planner silently drops off the fused
-Pallas backward-search kernel onto the XLA pair descent.  This module
-restores the kernel path by sharding the collection over a 1-D ``docs``
-mesh axis (``repro.dist.sharding``):
+``BACKWARD_SEARCH_VMEM_BUDGET`` its build selects the XLA pair descent
+instead of the fused Pallas backward-search kernel.  This module restores
+the kernel path by sharding the collection over a 1-D ``docs`` mesh axis
+(``repro.dist.sharding``):
 
 * **Partitioning** — documents are split into contiguous shards
   (``doc_shard_bounds``); each shard indexes its own sub-collection
@@ -18,15 +18,16 @@ mesh axis (``repro.dist.sharding``):
 
 * **Execution** — ONE ``jax.jit`` program per endpoint x shape bucket, AOT
   compiled into the same shape-bucketed cache as the single-device engine.
-  Inside the program the per-shard executors are unrolled at trace time
-  (the per-shard pytrees are heterogeneous — different n, runs, PDL
-  grammars — so they cannot be stacked and vmapped); the fused
-  backward-search kernel therefore launches once **per shard** with a
+  Inside the program one ``shard_map`` over the docs axis runs the
+  executors: each device switches on its axis index to its own shard's
+  branch (the per-shard pytrees are heterogeneous — different n, runs,
+  PDL grammars — so they cannot be stacked and vmapped).  Shard s's work
+  therefore runs on device s only, and the fused kernels — which Mosaic
+  cannot partition automatically — launch once **per shard** with a
   per-shard VMEM footprint (the per-shard launch-count contract in
-  ``repro.analysis.contracts``).  Per-shard results are stacked [S, ...],
-  constrained to ``PartitionSpec("docs", ...)`` so the partitioner places
-  each shard's compute with its output slice, and merged by a
-  ``shard_map``-ped reduction stage.
+  ``repro.analysis.contracts``).  Per-shard results come out stacked
+  [S, ...] along the docs axis and are merged by a ``shard_map``-ped
+  reduction stage.
 
 * **Merge algebra** (all on device, collectives allowlisted to
   ``psum`` / ``all_gather``):
@@ -50,8 +51,8 @@ mesh axis (``repro.dist.sharding``):
 
 Placement note: ``jax.jit`` rejects mixed single-device placements, so the
 per-shard index leaves are placed **replicated** over the docs mesh
-(``docs_index_shardings``) and the partitioner is steered by the output
-constraints alone.  True per-device residency (shard s's leaves living
+(``docs_index_shardings``); only the taken branch reads them.  True
+per-device residency (shard s's leaves living
 only on device s) is the multi-host follow-up recorded in
 docs/SHARDING.md; the kernel-path restoration is unaffected because the
 kernel's working set is the per-launch (per-shard) wavelet matrix.
@@ -65,7 +66,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.common import IDX
 from repro.core.sada import sada_count_batch
@@ -77,7 +78,6 @@ from repro.dist.sharding import (
     doc_shard_bounds,
     docs_index_shardings,
     docs_mesh_size,
-    shard_map_compat,
 )
 from repro.serve import faults
 from repro.serve.planner import ENGINE_BRUTE, ENGINE_CODES, plan_queries
@@ -90,15 +90,44 @@ from repro.serve.retrieval import (
     _list_program,
     _pow2_ceil,
     _topk_program,
+    kernel_selection,
 )
 
 _BIG = np.iinfo(np.int32).max
 
 
-def _wsc(x, mesh):
-    """Constrain a stacked [S, ...] per-shard result to the docs axis."""
-    spec = P(DOCS_AXIS, *([None] * (x.ndim - 1)))
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+def _per_shard(mesh, fn, shard_idx, *args, stacked=()):
+    """``fn(s, shard_s, *args, *stacked_s)`` for every docs shard ``s``, on
+    its own device; outputs stacked ``[S, ...]`` along the docs axis.
+
+    One ``shard_map`` over the docs axis whose body switches on the
+    device's axis index to its shard's executors: the per-shard pytrees
+    differ in shape, so they ride in replicated and only the taken branch
+    reads them.  A Mosaic kernel cannot be partitioned automatically, so
+    this is also what lets the fused kernels run on a docs mesh.
+    ``stacked`` operands are ``[S, ...]`` per-shard values (earlier
+    outputs of this function); shard ``s`` sees its own row."""
+
+    def body(idx, args, stacked):
+        rows = [x[0] for x in stacked]
+        branches = [
+            functools.partial(
+                lambda s, *ops: jax.tree.map(
+                    lambda x: x[None], fn(s, idx[s], *ops)
+                ),
+                s,
+            )
+            for s in range(len(idx))
+        ]
+        return jax.lax.switch(
+            jax.lax.axis_index(DOCS_AXIS), branches, *args, *rows
+        )
+
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
+        in_specs=(P(), P(), P(DOCS_AXIS)),
+        out_specs=P(DOCS_AXIS),
+    )(shard_idx, args, tuple(stacked))
 
 
 def _shard_args(shards):
@@ -123,31 +152,27 @@ def _sharded_plan_program(
     and engine choices are shard-local (each shard dispatches on its own
     occ/df balance), occurrence and document counts are collection-global.
     """
-    lo, hi, occ, df, engine = [], [], [], [], []
-    for csa, _ilcp, _pdl, _pdlt, sada, _da in shard_idx:
-        plan = plan_queries(
-            csa, sada, patterns, lengths, threshold, forced,
-            use_kernel=use_kernel,
-        )
-        lo.append(plan.lo)
-        hi.append(plan.hi)
-        occ.append(plan.occ)
-        df.append(plan.df)
-        engine.append(plan.engine)
-    occ_sb = _wsc(jnp.stack(occ), mesh)
-    df_sb = _wsc(jnp.stack(df), mesh)
+    def plan(s, shard, patterns, lengths, threshold, forced):
+        csa, _ilcp, _pdl, _pdlt, sada, _da = shard
+        p = plan_queries(csa, sada, patterns, lengths, threshold, forced,
+                         use_kernel=use_kernel)
+        return p.lo, p.hi, p.engine, p.occ, p.df
+
+    lo, hi, engine, occ_sb, df_sb = _per_shard(
+        mesh, plan, shard_idx, patterns, lengths, threshold, forced
+    )
 
     def merge(occ_local, df_local):
         g_occ = jax.lax.psum(jnp.sum(occ_local, axis=0), DOCS_AXIS)
         g_df = jax.lax.psum(jnp.sum(df_local, axis=0), DOCS_AXIS)
         return g_occ, g_df
 
-    g_occ, g_df = shard_map_compat(
-        merge, mesh,
+    g_occ, g_df = jax.shard_map(
+        merge, mesh=mesh, check_vma=False,
         in_specs=(P(DOCS_AXIS, None), P(DOCS_AXIS, None)),
         out_specs=(P(None), P(None)),
     )(occ_sb, df_sb)
-    return jnp.stack(lo), jnp.stack(hi), jnp.stack(engine), g_occ, g_df
+    return lo, hi, engine, g_occ, g_df
 
 
 def _sharded_list_program(
@@ -160,16 +185,17 @@ def _sharded_list_program(
     on the kernel path the fused ILCP listing kernel launches once PER
     SHARD (like backward search), with a per-shard VMEM footprint —
     restoring the listing kernel for stacks past ILCP_LIST_VMEM_BUDGET."""
-    per_docs, per_cnt = [], []
-    for s, (csa, ilcp, pdl, _pdlt, sada, da) in enumerate(shard_idx):
+    def listing(s, shard, patterns, lengths, threshold, forced):
+        csa, ilcp, pdl, _pdlt, sada, da = shard
         docs, cnt, _plan = _list_program(
             max_df, brute_win, max_buf, use_kernel, use_list_kernel,
             csa, ilcp, pdl, da, sada, patterns, lengths, threshold, forced,
         )
-        per_docs.append(jnp.where(docs >= 0, docs + doc_bases[s], -1))
-        per_cnt.append(cnt)
-    docs_sb = _wsc(jnp.stack(per_docs), mesh)   # [S, B, max_df]
-    cnt_sb = _wsc(jnp.stack(per_cnt), mesh)     # [S, B]
+        return jnp.where(docs >= 0, docs + doc_bases[s], -1), cnt
+
+    docs_sb, cnt_sb = _per_shard(           # [S, B, max_df], [S, B]
+        mesh, listing, shard_idx, patterns, lengths, threshold, forced
+    )
 
     def merge(docs_local, cnt_local):
         total = jax.lax.psum(jnp.sum(cnt_local, axis=0), DOCS_AXIS)
@@ -181,8 +207,8 @@ def _sharded_list_program(
         docs = jnp.where(s == _BIG, -1, s)      # concat + sort, no dedup
         return docs.astype(IDX), jnp.minimum(total, W).astype(IDX)
 
-    return shard_map_compat(
-        merge, mesh,
+    return jax.shard_map(
+        merge, mesh=mesh, check_vma=False,
         in_specs=(P(DOCS_AXIS, None, None), P(DOCS_AXIS, None)),
         out_specs=(P(None, None), P(None)),
     )(docs_sb, cnt_sb)
@@ -197,16 +223,17 @@ def _sharded_topk_program(
     Exact because documents are shard-disjoint: a document's tf is computed
     entirely inside its shard, so every global top-k document appears in
     its own shard's local top-k."""
-    per_docs, per_tf = [], []
-    for s, (csa, _ilcp, _pdl, pdl_t, sada, _da) in enumerate(shard_idx):
+    def topk(s, shard, patterns, lengths, threshold, forced):
+        csa, _ilcp, _pdl, pdl_t, sada, _da = shard
         docs, tfs, _plan = _topk_program(
             k, max_df, brute_win, max_buf, use_kernel,
             csa, pdl_t, sada, patterns, lengths, threshold, forced,
         )
-        per_docs.append(jnp.where(docs >= 0, docs + doc_bases[s], -1))
-        per_tf.append(tfs)
-    docs_sb = _wsc(jnp.stack(per_docs), mesh)   # [S, B, k]
-    tf_sb = _wsc(jnp.stack(per_tf), mesh)
+        return jnp.where(docs >= 0, docs + doc_bases[s], -1), tfs
+
+    docs_sb, tf_sb = _per_shard(            # [S, B, k] each
+        mesh, topk, shard_idx, patterns, lengths, threshold, forced
+    )
 
     def merge(docs_local, tf_local):
         alld = jax.lax.all_gather(docs_local, DOCS_AXIS, axis=0, tiled=True)
@@ -226,8 +253,8 @@ def _sharded_topk_program(
             jnp.where(good, tfs, 0).astype(IDX),
         )
 
-    return shard_map_compat(
-        merge, mesh,
+    return jax.shard_map(
+        merge, mesh=mesh, check_vma=False,
         in_specs=(P(DOCS_AXIS, None, None), P(DOCS_AXIS, None, None)),
         out_specs=(P(None, None), P(None, None)),
     )(docs_sb, tf_sb)
@@ -240,37 +267,40 @@ def _sharded_tfidf_program(
     """tf-idf in two merge stages: psum global df, then score per shard
     with global weights and gather-merge by (score desc, id asc)."""
     Q, T, _m = patterns.shape
-    per_ranges, per_dfs = [], []
-    valid = None
-    for csa, _ilcp, _pdl, _pdlt, sada, _da in shard_idx:
+
+    def term_ranges(s, shard, patterns, lengths):
+        csa, _ilcp, _pdl, _pdlt, sada, _da = shard
         ranges, valid = term_ranges_batch(
             csa, patterns, lengths, use_kernel=use_kernel
         )
         flat = ranges.reshape(Q * T, 2)
         dfs = sada_count_batch(sada, flat[:, 0], flat[:, 1]).reshape(Q, T)
-        per_ranges.append(ranges)
-        per_dfs.append(dfs)
-    dfs_sb = _wsc(jnp.stack(per_dfs), mesh)     # [S, Q, T]
+        return ranges, valid, dfs
+
+    ranges_sb, valid_sb, dfs_sb = _per_shard(   # [S, Q, T, 2], [S, Q, T] x 2
+        mesh, term_ranges, shard_idx, patterns, lengths
+    )
 
     def merge_df(dfs_local):
         return jax.lax.psum(jnp.sum(dfs_local, axis=0), DOCS_AXIS)
 
-    g_dfs = shard_map_compat(
-        merge_df, mesh,
+    g_dfs = jax.shard_map(
+        merge_df, mesh=mesh, check_vma=False,
         in_specs=P(DOCS_AXIS, None, None),
         out_specs=P(None, None),
     )(dfs_sb)                                   # [Q, T] global df, replicated
 
-    per_docs, per_scores = [], []
-    for s, (csa, _ilcp, _pdl, pdl_t, sada, _da) in enumerate(shard_idx):
+    def score(s, shard, g_dfs, ranges, valid):
+        csa, _ilcp, _pdl, pdl_t, sada, _da = shard
         docs, scores = tfidf_topk_batch(
-            pdl_t, csa, sada, per_ranges[s], valid, k, conjunctive,
+            pdl_t, csa, sada, ranges, valid, k, conjunctive,
             max_buf=max_buf, dfs_batch=g_dfs, n_docs=n_docs,
         )
-        per_docs.append(jnp.where(docs >= 0, docs + doc_bases[s], -1))
-        per_scores.append(scores)
-    docs_sb = _wsc(jnp.stack(per_docs), mesh)     # [S, Q, k]
-    score_sb = _wsc(jnp.stack(per_scores), mesh)
+        return jnp.where(docs >= 0, docs + doc_bases[s], -1), scores
+
+    docs_sb, score_sb = _per_shard(             # [S, Q, k] each
+        mesh, score, shard_idx, g_dfs, stacked=(ranges_sb, valid_sb)
+    )
 
     def merge(docs_local, score_local):
         alld = jax.lax.all_gather(docs_local, DOCS_AXIS, axis=0, tiled=True)
@@ -285,8 +315,8 @@ def _sharded_tfidf_program(
         )
         return md, ms
 
-    return shard_map_compat(
-        merge, mesh,
+    return jax.shard_map(
+        merge, mesh=mesh, check_vma=False,
         in_specs=(P(DOCS_AXIS, None, None), P(DOCS_AXIS, None, None)),
         out_specs=(P(None, None), P(None, None)),
     )(docs_sb, score_sb)
@@ -332,18 +362,12 @@ class ShardedRetrievalService:
     ):
         n_shards = docs_mesh_size(mesh)
         bounds = doc_shard_bounds(coll.d, n_shards)
-        if use_search_kernel is None:
-            use_search_kernel = jax.default_backend() == "tpu"
-        if use_list_kernel is None:
-            use_list_kernel = jax.default_backend() == "tpu"
         shards = []
         for dlo, dhi in bounds:
             sub = subcollection(coll, dlo, dhi)
             shard = RetrievalService.build(
                 sub, block_size=block_size, beta=beta,
                 sada_variant=sada_variant, sample_rate=sample_rate,
-                use_search_kernel=use_search_kernel,
-                use_list_kernel=use_list_kernel,
                 brute_window=brute_window, validate=False,
             )
             # jit rejects mixed single-device placements: leaves live
@@ -355,6 +379,15 @@ class ShardedRetrievalService:
                     jax.device_put(leaf, docs_index_shardings(mesh, leaf)),
                 )
             shards.append(shard)
+        # one selection for every shard's launch, as the flat build makes
+        # it: a kernel runs when every shard's tables fit its budget
+        search_k, list_k = kernel_selection(
+            shards, mesh.devices.flat[0].platform
+        )
+        if use_search_kernel is None:
+            use_search_kernel = search_k
+        if use_list_kernel is None:
+            use_list_kernel = list_k
         svc = cls(
             coll=coll,
             mesh=mesh,

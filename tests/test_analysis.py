@@ -89,7 +89,7 @@ def test_gather_and_find_primitives():
 
 
 def test_wide_dtype_eqns_flags_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jpr = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0
         )(jnp.ones((2,), jnp.float32))
@@ -188,7 +188,7 @@ def test_audit_catches_f64_widening(svc):
         leaf = jax.tree.leaves(out)[0]
         return out, leaf.astype(jnp.float64).sum()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         traced = jax.make_jaxpr(widened)(*build_args(8, 8))
     contract = EndpointContract("plan", (8, 8), "xla", pallas_calls=0)
     vs = audit_jaxpr(traced, contract)

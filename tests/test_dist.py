@@ -65,7 +65,7 @@ params = init_params(cfg0, key)
 tokens = jax.random.randint(key, (4, 16), 0, 64)
 ref = float(forward_train(cfg0, params, tokens, tokens))
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dataclasses.replace(cfg0, ep_mesh=mesh, ep_dp_axes=("data",), ep_fsdp=False)
 with mesh:
     got = float(jax.jit(lambda p, t: forward_train(cfg, p, t, t))(params, tokens))

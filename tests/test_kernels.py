@@ -282,8 +282,10 @@ def test_backward_search_oob_stays_empty():
 
 
 def test_backward_search_odd_shape_fallback(monkeypatch):
-    """Empty batch / zero-width patterns / over-budget indexes must take the
-    pure-jnp path: correct results, zero pallas_call in the jaxpr."""
+    """Empty batch / zero-width patterns have closed forms: correct
+    results, zero pallas_call in the jaxpr.  An over-budget index is a
+    build-time decision (``backward_search_fits``); the wrapper itself
+    never swaps the kernel for the oracle."""
     n, sigma, max_m = 200, 5, 6
     seq, wm, base, counts = _bws_index(n, sigma, seed=4)
 
@@ -314,14 +316,17 @@ def test_backward_search_odd_shape_fallback(monkeypatch):
     )
     assert np.all(np.asarray(lo) == 0) and np.all(np.asarray(hi) == n)
 
-    # over the VMEM budget: same integers through the oracle, no launch
+    # over the VMEM budget: the index no longer fits, but a launch asked
+    # for is a launch made — with the oracle's integers
     pats, lens = _bws_patterns(seq, sigma, 16, max_m, seed=11)
-    want = ops.backward_search(
-        wm.words, wm.ones_prefix, wm.zcount, base, pats, lens,
-        n=n, sigma=sigma, interpret=True,
+    want = ref.backward_search_ref(
+        wm.words, wm.ones_prefix, wm.zcount, base,
+        _reversed_pats(pats, lens), lens, n=n, sigma=sigma,
     )
+    assert ops.backward_search_fits(wm.words, wm.ones_prefix)
     monkeypatch.setattr(ops, "BACKWARD_SEARCH_VMEM_BUDGET", 1)
-    assert launches(pats, lens) == 0
+    assert not ops.backward_search_fits(wm.words, wm.ones_prefix)
+    assert launches(pats, lens) == 1
     got = ops.backward_search(
         wm.words, wm.ones_prefix, wm.zcount, base, pats, lens,
         n=n, sigma=sigma, interpret=True,
@@ -471,8 +476,9 @@ def test_ilcp_list_kernel_parity(max_df, block_q):
 
 def test_ilcp_list_launch_and_fallbacks(monkeypatch):
     """Launch-count + fallback contract of the ``ops.ilcp_list`` wrapper:
-    ONE pallas_call on the kernel path; zero for B == 0, max_df == 0, and
-    a pinched VMEM budget — each fallback bit-identical to the kernel."""
+    ONE pallas_call on the kernel path; zero for B == 0 and max_df == 0
+    (closed forms).  A pinched VMEM budget flips the build-time fit test
+    (``ilcp_list_fits``) but never the wrapper: it still launches."""
     coll, data, index, da, lo, hi = _ilcp_fixture()
 
     def run(l, h, max_df=8):
@@ -490,12 +496,18 @@ def test_ilcp_list_launch_and_fallbacks(monkeypatch):
     docs0, cnt0 = run(e, e)
     assert docs0.shape == (0, 8) and cnt0.shape == (0,)
 
-    # max_df == 0 routes to the oracle
+    # max_df == 0 has the closed form: no documents
     assert _list_launches(lambda a, b: run(a, b, max_df=0), lo, hi) == 0
+    docs0, cnt0 = run(lo, hi, max_df=0)
+    assert docs0.shape == (lo.shape[0], 0) and not np.asarray(cnt0).any()
 
-    # over the VMEM budget: same integers through the oracle, no launch
+    # over the VMEM budget: the fit test says no, the wrapper still runs
+    # the kernel it was asked for, with the same integers
+    tables = (index.vilcp, index.rmq.table, index.run_starts, da)
+    assert ops.ilcp_list_fits(*tables, d=coll.d)
     monkeypatch.setattr(ops, "ILCP_LIST_VMEM_BUDGET", 1)
-    assert _list_launches(run, lo, hi) == 0
+    assert not ops.ilcp_list_fits(*tables, d=coll.d)
+    assert _list_launches(run, lo, hi) == 1
     got = run(lo, hi)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -135,6 +135,21 @@ def test_planner_and_compile_faults_degrade(svc_pats):
     )
 
 
+def test_lowering_error_propagates_not_degrades(svc_pats, monkeypatch):
+    """A program the device cannot lower is not a transient failure: the
+    error leaves ``serve`` instead of turning into a degraded answer."""
+    svc, pats = svc_pats
+
+    def refuse(kind, statics, build_fn, args):
+        raise NotImplementedError("Unimplemented primitive in Pallas TPU lowering")
+
+    monkeypatch.setattr(svc, "_compiled", refuse)
+    rt = _runtime(svc)
+    with pytest.raises(NotImplementedError):
+        rt.serve([("list", pats[0])])
+    assert rt.metrics.retries == 0 and rt.metrics.degraded == 0
+
+
 def test_mixed_fault_workload_answers_everything(svc_pats):
     svc, pats = svc_pats
     specs = parse_fault_specs("executor_fail,slow_list,compile_error",

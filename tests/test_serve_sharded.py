@@ -27,7 +27,7 @@ from repro.errors import IndexIntegrityError
 from repro.kernels import ops
 from repro.serve import faults
 from repro.serve.faults import FaultSpec
-from repro.serve.retrieval import RetrievalService
+from repro.serve.retrieval import RetrievalService, kernel_selection
 from repro.serve.runtime import RuntimeConfig, ServeRuntime
 from repro.serve.sharded import ShardedRetrievalService
 
@@ -41,10 +41,7 @@ GENEROUS = 300.0
 
 
 def _resident_bytes(csa):
-    return ops.backward_search_resident_bytes(
-        csa.wm.words, csa.wm.ones_prefix, csa.wm.zcount,
-        csa.counts[: csa.sigma] - csa.wm.sym_starts,
-    )
+    return ops.backward_search_resident_bytes(csa.wm.words, csa.wm.ones_prefix)
 
 
 @pytest.fixture(scope="module")
@@ -239,22 +236,34 @@ def test_validate_rejects_bad_partition(skewed):
 
 def test_kernel_restored_when_sharded(setup, monkeypatch):
     """With the VMEM budget pinched between the per-shard and the global
-    wavelet-matrix footprint, the unsharded program falls back to the XLA
-    pair descent (zero pallas_calls) while the sharded program launches the
-    fused kernel once per shard — and still answers bit-identically."""
+    wavelet-matrix footprint, the build's TPU selection puts the unsharded
+    index on the XLA pair descent (zero pallas_calls) and the sharded one
+    on the fused kernel, once per shard — which still answers
+    bit-identically."""
     coll, base, svc, pats = setup
     from repro.analysis.jaxpr import count_primitive
 
-    global_bytes = _resident_bytes(base.csa)
-    shard_bytes = max(_resident_bytes(sh.csa) for sh in svc.shards)
+    # resident tables pad to whole (8, 128) tiles, so the wavelet matrix
+    # must span several tiles for a quarter of it to need fewer
+    big = generate(SyntheticSpec("version", n_base=4, n_variants=6,
+                                 base_len=500, mutation_rate=0.01, seed=5))
+    big_flat = RetrievalService.build(big, block_size=16, beta=8.0,
+                                      validate=False)
+    big_sharded = RetrievalService.build(big, mesh=svc.mesh, block_size=16,
+                                         beta=8.0, validate=False)
+    global_bytes = _resident_bytes(big_flat.csa)
+    shard_bytes = max(_resident_bytes(sh.csa) for sh in big_sharded.shards)
     assert shard_bytes < global_bytes
     budget = (shard_bytes + global_bytes) // 2
     monkeypatch.setattr(ops, "BACKWARD_SEARCH_VMEM_BUDGET", budget)
 
-    unsharded = base.trace_endpoint("plan", use_kernel=True)
-    assert count_primitive(unsharded, "pallas_call") == 0  # over budget
-    sharded = svc.trace_endpoint("plan", use_kernel=True)
-    assert count_primitive(sharded, "pallas_call") == svc.n_shards
+    flat_search, _ = kernel_selection([big_flat], "tpu")
+    shard_search, _ = kernel_selection(big_sharded.shards, "tpu")
+    assert not flat_search and shard_search  # over budget only unsharded
+    unsharded = big_flat.trace_endpoint("plan", use_kernel=flat_search)
+    assert count_primitive(unsharded, "pallas_call") == 0
+    sharded = big_sharded.trace_endpoint("plan", use_kernel=shard_search)
+    assert count_primitive(sharded, "pallas_call") == big_sharded.n_shards
 
     # end to end through the kernel (interpret mode off-TPU): same answers
     svc_k = ShardedRetrievalService.build(
@@ -328,18 +337,17 @@ def test_runtime_fault_injection_degrades_to_sharded_reference(setup):
 def test_list_kernel_restored_when_sharded(setup, monkeypatch):
     """Listing-kernel counterpart of the restoration contract: with the
     listing VMEM budget pinched between the per-shard and the global
-    footprint (resident tables + tiles + scratch), the unsharded list
-    program loses its listing launch while the sharded program keeps one
-    fused listing launch per shard — and both kernels together make the
+    resident tables (plus the V scratch), the build's TPU selection takes
+    the listing launch from the unsharded list program and keeps one fused
+    listing launch per shard — and both kernels together make the
     per-shard launch count 2S."""
     coll, base, svc, pats = setup
     from repro.analysis.jaxpr import count_primitive
 
     def list_bytes(s):
-        return ops.block_meta_bytes(ops.ilcp_list_block_meta(
+        return ops.ilcp_list_resident_bytes(
             s.ilcp.vilcp, s.ilcp.rmq.table, s.ilcp.run_starts, s.da,
-            batch=8, d=s.ilcp.d, max_df=64,
-        ))
+        ) + ops.ilcp_list_scratch_bytes(s.ilcp.d)
 
     global_bytes = list_bytes(base)
     shard_bytes = max(list_bytes(sh) for sh in svc.shards)
@@ -347,12 +355,15 @@ def test_list_kernel_restored_when_sharded(setup, monkeypatch):
     budget = (shard_bytes + global_bytes) // 2
     monkeypatch.setattr(ops, "ILCP_LIST_VMEM_BUDGET", budget)
 
+    _, flat_list = kernel_selection([base], "tpu")
+    _, shard_list = kernel_selection(svc.shards, "tpu")
+    assert not flat_list and shard_list  # over budget only unsharded
     unsharded = base.trace_endpoint(
-        "list", use_kernel=False, use_list_kernel=True
+        "list", use_kernel=False, use_list_kernel=flat_list
     )
-    assert count_primitive(unsharded, "pallas_call") == 0  # over budget
+    assert count_primitive(unsharded, "pallas_call") == 0
     sharded = svc.trace_endpoint(
-        "list", use_kernel=False, use_list_kernel=True
+        "list", use_kernel=False, use_list_kernel=shard_list
     )
     assert count_primitive(sharded, "pallas_call") == svc.n_shards
     both = svc.trace_endpoint("list", use_kernel=True, use_list_kernel=True)
