@@ -153,5 +153,6 @@ def backward_search_pallas(
             jax.ShapeDtypeStruct((tiles, 1, bq), jnp.int32),
         ],
         interpret=interpret,
+        name="backward_search",
     )(len_p, zcount, base, pat_p, as_rows(words), as_rows(ones_prefix))
     return lo.reshape(bpad)[:B], hi.reshape(bpad)[:B]

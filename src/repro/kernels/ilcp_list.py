@@ -219,6 +219,7 @@ def ilcp_list_pallas(
             pltpu.VMEM(v_rows_shape(d), jnp.int32),          # V (bit-packed)
         ],
         interpret=interpret,
+        name="ilcp_list",
     )(
         per_query(lo, 0), per_query(hi, 0),
         per_query(lo_run, 0), per_query(hi_run, -1),

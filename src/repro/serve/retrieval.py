@@ -79,7 +79,7 @@ from repro.core.suffix import Collection, build_suffix_data
 from repro.core.tfidf import term_ranges_batch, tfidf_topk_batch
 from repro.data.collections import normalize_patterns, pad_patterns
 from repro.kernels import ops
-from repro.serve import faults
+from repro.serve import faults, obs
 from repro.serve.planner import (
     ENGINE_BRUTE,
     ENGINE_CODES,
@@ -123,6 +123,29 @@ def _pow2_ceil(x: int) -> int:
     return 1 if x <= 1 else 1 << (x - 1).bit_length()
 
 
+_ENGINE_NAMES = {ENGINE_EMPTY: "empty", ENGINE_BRUTE: "brute",
+                 ENGINE_ILCP: "ilcp", ENGINE_PDL: "pdl"}
+
+
+def _engine_rows(engine) -> dict:
+    """Rows of a plan by the engine it assigned them to."""
+    counts = np.bincount(engine, minlength=len(_ENGINE_NAMES))
+    return {name: int(counts[code]) for code, name in _ENGINE_NAMES.items()}
+
+
+def _to_device(*arrays):
+    """Host arrays put on the device, one counted transfer each."""
+    obs.add(transfers=len(arrays))
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _to_host(arrays, rows: int):
+    """A program's outputs copied to the host (one blocking, counted
+    transfer each), trimmed to the batch's true ``rows``."""
+    obs.add(transfers=len(arrays))
+    return tuple(np.asarray(a)[:rows] for a in arrays)
+
+
 # ---------------------------------------------------------------------------
 # Fused programs (pure functions of the index pytrees; compiled per bucket)
 # ---------------------------------------------------------------------------
@@ -136,10 +159,11 @@ def _sorted_rows(docs):
 
 
 def _plan_program(use_kernel, csa, sada, patterns, lengths, threshold, forced):
-    return plan_queries(
-        csa, sada, patterns, lengths, threshold, forced,
-        use_kernel=use_kernel,
-    )
+    with jax.named_scope("plan"):
+        return plan_queries(
+            csa, sada, patterns, lengths, threshold, forced,
+            use_kernel=use_kernel,
+        )
 
 
 def _list_program(
@@ -154,18 +178,22 @@ def _list_program(
     Pallas listing kernel (one launch — the program's second, after the
     planner's backward search) or the XLA vmap'd while_loop.
     """
-    plan = plan_queries(
-        csa, sada, patterns, lengths, threshold, forced,
-        use_kernel=use_kernel,
-    )
-    bl, bh = masked_ranges(plan, ENGINE_BRUTE)
-    docs_b, cnt_b, _ = brute_list_csa_batch(csa, bl, bh, brute_win, max_df)
-    il, ih = masked_ranges(plan, ENGINE_ILCP)
-    docs_i, cnt_i = ilcp_list_docs_da_planned(
-        ilcp, da, il, ih, max_df, use_kernel=use_list_kernel
-    )
-    pl, ph = masked_ranges(plan, ENGINE_PDL)
-    docs_p, cnt_p = pdl_list_docs_batch(pdl, csa, pl, ph, max_df, max_buf)
+    with jax.named_scope("plan"):
+        plan = plan_queries(
+            csa, sada, patterns, lengths, threshold, forced,
+            use_kernel=use_kernel,
+        )
+    with jax.named_scope("brute"):
+        bl, bh = masked_ranges(plan, ENGINE_BRUTE)
+        docs_b, cnt_b, _ = brute_list_csa_batch(csa, bl, bh, brute_win, max_df)
+    with jax.named_scope("ilcp"):
+        il, ih = masked_ranges(plan, ENGINE_ILCP)
+        docs_i, cnt_i = ilcp_list_docs_da_planned(
+            ilcp, da, il, ih, max_df, use_kernel=use_list_kernel
+        )
+    with jax.named_scope("pdl"):
+        pl, ph = masked_ranges(plan, ENGINE_PDL)
+        docs_p, cnt_p = pdl_list_docs_batch(pdl, csa, pl, ph, max_df, max_buf)
 
     eng = plan.engine[:, None]
     docs = jnp.where(
@@ -188,18 +216,21 @@ def _topk_program(
     """top-k as one program.  Brute-assigned queries take the sorted-window
     path (exact tf within the occ window); ILCP has no top-k structure, so
     its queries ride the PDL lists, as in the paper's Section 6.3 lineup."""
-    plan = plan_queries(
-        csa, sada, patterns, lengths, threshold, forced,
-        use_kernel=use_kernel,
-    )
-    bl, bh = masked_ranges(plan, ENGINE_BRUTE)
-    d_b, c_b, f_b = brute_list_csa_batch(csa, bl, bh, brute_win, max_df)
-    tb_docs, tb_tf = brute_topk_batch(d_b, c_b, f_b, k)
+    with jax.named_scope("plan"):
+        plan = plan_queries(
+            csa, sada, patterns, lengths, threshold, forced,
+            use_kernel=use_kernel,
+        )
+    with jax.named_scope("brute"):
+        bl, bh = masked_ranges(plan, ENGINE_BRUTE)
+        d_b, c_b, f_b = brute_list_csa_batch(csa, bl, bh, brute_win, max_df)
+        tb_docs, tb_tf = brute_topk_batch(d_b, c_b, f_b, k)
 
-    use_pdl = (plan.engine == ENGINE_PDL) | (plan.engine == ENGINE_ILCP)
-    pl = jnp.where(use_pdl, plan.lo, 0)
-    ph = jnp.where(use_pdl, plan.hi, 0)
-    tp_docs, tp_tf = pdl_topk_batch(pdl_t, csa, pl, ph, k, max_buf)
+    with jax.named_scope("pdl"):
+        use_pdl = (plan.engine == ENGINE_PDL) | (plan.engine == ENGINE_ILCP)
+        pl = jnp.where(use_pdl, plan.lo, 0)
+        ph = jnp.where(use_pdl, plan.hi, 0)
+        tp_docs, tp_tf = pdl_topk_batch(pdl_t, csa, pl, ph, k, max_buf)
 
     is_brute = (plan.engine == ENGINE_BRUTE)[:, None]
     docs = jnp.where(is_brute, tb_docs, tp_docs)
@@ -216,10 +247,14 @@ def _tfidf_program(
     batched ranked-AND/OR scoring.  ``use_kernel`` selects the same
     backward-search backend as the planner (True = one fused Pallas launch
     for the whole [Q*T] term batch)."""
-    ranges, valid = term_ranges_batch(csa, patterns, lengths, use_kernel=use_kernel)
-    return tfidf_topk_batch(
-        pdl_t, csa, sada, ranges, valid, k, conjunctive, max_buf=max_buf
-    )
+    with jax.named_scope("plan"):
+        ranges, valid = term_ranges_batch(
+            csa, patterns, lengths, use_kernel=use_kernel
+        )
+    with jax.named_scope("tfidf"):
+        return tfidf_topk_batch(
+            pdl_t, csa, sada, ranges, valid, k, conjunctive, max_buf=max_buf
+        )
 
 
 def kernel_selection(parts, platform: str) -> tuple[bool, bool]:
@@ -344,7 +379,8 @@ class RetrievalService:
         exe = self._cache.get(key)
         if exe is None:
             faults.fire(f"compile:{kind}")
-            exe = jax.jit(build_fn()).lower(*args).compile()
+            with obs.span("serve.compile", kind=kind, statics=str(statics)):
+                exe = jax.jit(build_fn()).lower(*args).compile()
             self._cache[key] = exe
             self.compile_counts[kind] = self.compile_counts.get(kind, 0) + 1
         return exe
@@ -366,12 +402,19 @@ class RetrievalService:
         out[:B, :m] = pats
         lns = np.zeros(Bb, np.int32)
         lns[:B] = lens
-        return jnp.asarray(out), jnp.asarray(lns), B
+        return *_to_device(out, lns), B
 
     def _knobs(self, engine: str):
-        thresh = jnp.float32(self.occ_df_threshold)
-        forced = jnp.int32(ENGINE_CODES[engine])
-        return thresh, forced
+        return _to_device(np.float32(self.occ_df_threshold),
+                          np.int32(ENGINE_CODES[engine]))
+
+    def _pack(self, patterns, engine: str):
+        """(patterns, lengths, threshold, forced, true batch size) of one
+        planned call, on the device."""
+        with obs.span("serve.pack") as sp:
+            pats, lens, B = self._pad_batch(patterns)
+            sp.set(rows=B, padded_rows=pats.shape[0])
+            return (pats, lens, *self._knobs(engine), B)
 
     def _brute_window_for(self, kind: str, bucket_key: tuple, patterns,
                           engine: str, max_buf: int) -> int:
@@ -388,7 +431,9 @@ class RetrievalService:
         reference path does."""
         if self.brute_window is not None:
             return min(self.brute_window, max_buf)
-        plan = self.plan(patterns, engine)
+        with obs.span("serve.window_plan") as sp:
+            plan = self.plan(patterns, engine)
+            sp.set(engine_rows=lambda: _engine_rows(plan["engine"]))
         occ = plan["occ"][plan["engine"] == ENGINE_BRUTE]
         needed = int(occ.max()) if occ.size else 0
         win = min(max(_pow2_ceil(needed), BRUTE_WINDOW_FLOOR), max_buf)
@@ -402,19 +447,18 @@ class RetrievalService:
     def plan(self, patterns, engine: str = "auto"):
         """Query plan for a pattern batch: host arrays (lo, hi, occ, df,
         engine), trimmed to the true batch size."""
-        pats, lens, B = self._pad_batch(patterns)
-        thresh, forced = self._knobs(engine)
+        pats, lens, thresh, forced, B = self._pack(patterns, engine)
         faults.fire("plan")
         exe = self._compiled(
             "plan", (pats.shape,),
             lambda: functools.partial(_plan_program, self.use_search_kernel),
             (self.csa, self.sada, pats, lens, thresh, forced),
         )
-        plan = exe(self.csa, self.sada, pats, lens, thresh, forced)
-        return {
-            name: np.asarray(getattr(plan, name))[:B]
-            for name in ("lo", "hi", "occ", "df", "engine")
-        }
+        names = ("lo", "hi", "occ", "df", "engine")
+        with obs.span("serve.run"):
+            plan = exe(self.csa, self.sada, pats, lens, thresh, forced)
+            return dict(zip(names, _to_host(
+                [getattr(plan, name) for name in names], B)))
 
     def ranges(self, patterns):
         p = self.plan(patterns)
@@ -447,8 +491,7 @@ class RetrievalService:
         -1 padded, counts int32[B]) — the zero-copy serving layout."""
         if not len(patterns):
             return np.zeros((0, max_df), np.int32), np.zeros(0, np.int32)
-        pats, lens, B = self._pad_batch(patterns)
-        thresh, forced = self._knobs(engine)
+        pats, lens, thresh, forced, B = self._pack(patterns, engine)
         win = self._brute_window_for(
             "list", (pats.shape, max_df, max_buf), patterns, engine, max_buf
         )
@@ -463,10 +506,10 @@ class RetrievalService:
             ),
             args,
         )
-        docs, cnt, _plan = exe(*args)
-        return faults.poison(
-            "executor:list", (np.asarray(docs)[:B], np.asarray(cnt)[:B])
-        )
+        with obs.span("serve.run"):
+            docs, cnt, _plan = exe(*args)
+            out = _to_host((docs, cnt), B)
+        return faults.poison("executor:list", out)
 
     def list_docs(self, patterns, max_df: int = 256, engine: str = "auto",
                   max_buf: int = 4096):
@@ -487,8 +530,7 @@ class RetrievalService:
         tf int32[B, k]), ranked by (tf desc, id asc)."""
         if not len(patterns):
             return np.zeros((0, k), np.int32), np.zeros((0, k), np.int32)
-        pats, lens, B = self._pad_batch(patterns)
-        thresh, forced = self._knobs(engine)
+        pats, lens, thresh, forced, B = self._pack(patterns, engine)
         max_df = self._topk_max_df(max_buf)
         win = self._brute_window_for(
             "topk", (pats.shape, k, max_buf), patterns, engine, max_buf
@@ -502,10 +544,10 @@ class RetrievalService:
             ),
             args,
         )
-        docs, tfs, _plan = exe(*args)
-        return faults.poison(
-            "executor:topk", (np.asarray(docs)[:B], np.asarray(tfs)[:B])
-        )
+        with obs.span("serve.run"):
+            docs, tfs, _plan = exe(*args)
+            out = _to_host((docs, tfs), B)
+        return faults.poison("executor:topk", out)
 
     def topk(self, patterns, k: int = 10, engine: str = "auto",
              max_buf: int = 4096):
@@ -525,23 +567,24 @@ class RetrievalService:
         Q = len(queries)
         if Q == 0:
             return np.zeros((0, k), np.int32), np.zeros((0, k), np.float32)
-        queries = [
-            normalize_patterns(
-                list(terms)[:max_terms], sigma=self.coll.sigma,
-                max_len=MAX_PATTERN_LEN,
-            )
-            for terms in queries
-        ]
-        m = max((len(t) for terms in queries for t in terms), default=1)
-        Qb, mb = _bucket_batch(Q), _bucket_len(max(m, 1))
-        pats = np.zeros((Qb, max_terms, mb), np.int32)
-        lens = np.zeros((Qb, max_terms), np.int32)
-        for qi, terms in enumerate(queries):
-            for ti, t in enumerate(terms):
-                pats[qi, ti, : len(t)] = t
-                lens[qi, ti] = len(t)
-        pats = jnp.asarray(pats)
-        lens = jnp.asarray(lens)
+        with obs.span("serve.pack") as sp:
+            queries = [
+                normalize_patterns(
+                    list(terms)[:max_terms], sigma=self.coll.sigma,
+                    max_len=MAX_PATTERN_LEN,
+                )
+                for terms in queries
+            ]
+            m = max((len(t) for terms in queries for t in terms), default=1)
+            Qb, mb = _bucket_batch(Q), _bucket_len(max(m, 1))
+            pats = np.zeros((Qb, max_terms, mb), np.int32)
+            lens = np.zeros((Qb, max_terms), np.int32)
+            for qi, terms in enumerate(queries):
+                for ti, t in enumerate(terms):
+                    pats[qi, ti, : len(t)] = t
+                    lens[qi, ti] = len(t)
+            pats, lens = _to_device(pats, lens)
+            sp.set(rows=Q, padded_rows=Qb)
         faults.fire("executor:tfidf")
         args = (self.csa, self.pdl_topk, self.sada, pats, lens)
         exe = self._compiled(
@@ -551,10 +594,9 @@ class RetrievalService:
             ),
             args,
         )
-        docs, scores = exe(*args)
-        return faults.poison(
-            "executor:tfidf", (np.asarray(docs)[:Q], np.asarray(scores)[:Q])
-        )
+        with obs.span("serve.run"):
+            out = _to_host(exe(*args), Q)
+        return faults.poison("executor:tfidf", out)
 
     def tfidf(self, queries, k: int = 10, conjunctive: bool = False,
               max_terms: int = 4, max_buf: int = 2048, engine: str = "auto"):

@@ -92,6 +92,7 @@ from repro.errors import (
     QueueFullError,
     TransientExecutionError,
 )
+from repro.serve import obs
 from repro.serve.retrieval import MAX_PATTERN_LEN
 
 KINDS = ("list", "topk", "count", "tfidf")
@@ -328,6 +329,10 @@ class ServeRuntime:
     # -- endpoint plumbing ---------------------------------------------------
 
     def _call(self, kind: str, reqs: list[Request], path: str):
+        with obs.span("serve.call", path=path):
+            return self._answers(kind, reqs, path)
+
+    def _answers(self, kind: str, reqs: list[Request], path: str):
         cfg = self.config
         pats = [r.payload for r in reqs]
         svc = self.svc
@@ -351,33 +356,37 @@ class ServeRuntime:
                 pats, max_df=max_df, engine="brute" if floor else "auto",
                 max_buf=cfg.max_buf,
             )
-            self._check_docs(docs, cnt, max_df)
-            return [docs[i, : cnt[i]].tolist() for i in range(len(reqs))]
+            with obs.span("serve.unpack"):
+                self._check_docs(docs, cnt, max_df)
+                return [docs[i, : cnt[i]].tolist() for i in range(len(reqs))]
         if kind == "topk":
             k = cfg.floor_k if floor else cfg.k
             docs, tfs = svc.topk_arrays(
                 pats, k=k, engine="brute" if floor else "auto",
                 max_buf=cfg.max_buf,
             )
-            self._check_docs(docs, None, k)
-            return [
-                [(int(d), int(t)) for d, t in zip(docs[i], tfs[i]) if d >= 0]
-                for i in range(len(reqs))
-            ]
+            with obs.span("serve.unpack"):
+                self._check_docs(docs, None, k)
+                return [
+                    [(int(d), int(t)) for d, t in zip(docs[i], tfs[i]) if d >= 0]
+                    for i in range(len(reqs))
+                ]
         if kind == "count":
             df = np.asarray(svc.count(pats))
-            if df.size and (df.min() < 0 or df.max() > svc.coll.d):
-                raise PoisonedResultError("df outside [0, d]")
-            return [int(x) for x in df]
+            with obs.span("serve.unpack"):
+                if df.size and (df.min() < 0 or df.max() > svc.coll.d):
+                    raise PoisonedResultError("df outside [0, d]")
+                return [int(x) for x in df]
         k = cfg.floor_k if floor else cfg.k
         docs, scores = svc.tfidf_arrays(
             pats, k=k, conjunctive=cfg.tfidf_conjunctive, max_buf=cfg.max_buf
         )
-        self._check_docs(docs, None, k)
-        return [
-            [(int(d), float(s)) for d, s in zip(docs[i], scores[i]) if d >= 0]
-            for i in range(len(reqs))
-        ]
+        with obs.span("serve.unpack"):
+            self._check_docs(docs, None, k)
+            return [
+                [(int(d), float(s)) for d, s in zip(docs[i], scores[i]) if d >= 0]
+                for i in range(len(reqs))
+            ]
 
     def _check_docs(self, docs, cnt, max_df) -> None:
         """Serving-ABI payload validation: a poisoned sentinel or an
@@ -504,10 +513,13 @@ class ServeRuntime:
 
     def step(self) -> list[Answer]:
         """Expire overdue queued requests, then cut and execute one batch."""
-        answers = self._expire(self._clock())
-        batch = self._cut_batch(self._clock())
-        if batch:
-            answers.extend(self._execute_batch(batch))
+        with obs.span("serve.step") as sp:
+            answers = self._expire(self._clock())
+            batch = self._cut_batch(self._clock())
+            if batch:
+                sp.set(kind=batch[0].kind, rows=len(batch),
+                       bucket=_pow2_ceil(len(batch)))
+                answers.extend(self._execute_batch(batch))
         return answers
 
     def run_until_idle(self) -> dict[int, Answer]:
