@@ -76,9 +76,8 @@ def _build_service(coll, n_shards: int, **kw):
 
 
 def _timed(fn, iters: int = ITERS, warmup: int = 1):
-    # warmup: compiles the bucket's program; one full pass over the batch
-    # cycle also settles the dispatch-aware brute windows (grow-only), so
-    # the timed loop below is pure execution
+    # warmup: compiles the bucket's program, so the timed loop below is
+    # pure execution
     for _ in range(warmup):
         fn()
     lat = []
@@ -101,9 +100,8 @@ def run_resilience(collection: str = "version-p001",
     the degradation ladder (retry, floor, host reference merge) must hold
     there too."""
     coll = bench_collections()[collection]
-    # pin the Brute-L window: the grow-only dispatch-aware sizing would
-    # recompile a bucket mid-run when a higher-occ pattern shows up, and
-    # those compiles would read as deadline misses rather than resilience
+    # the Brute-L window stays pinned at 512, the window of the recorded
+    # resilience runs, so that their numbers stay comparable
     svc, mesh_shape = _build_service(coll, n_shards, block_size=32, beta=8.0,
                                      brute_window=512)
     workload = random_substring_patterns(coll, max(n_queries, 64), 6, 64)
@@ -112,9 +110,9 @@ def run_resilience(collection: str = "version-p001",
                                          default_deadline_s=deadline_s))
     kinds = ("count", "list", "topk")
     rt.warmup(kinds=kinds, batch_sizes=(batch,))
-    # a realistic warm wave per kind: settles the grow-only brute windows
-    # (which recompile the bucket) and seeds the steady-state EMA, so the
-    # measured run sees no in-flight compiles
+    # a realistic warm wave per kind: compiles the workload's pattern-length
+    # buckets and seeds the steady-state EMA, so the measured run sees no
+    # in-flight compiles
     for kind in kinds:
         for _ in range(2):
             rt.serve([(kind, workload[int(i)])

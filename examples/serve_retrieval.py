@@ -63,8 +63,9 @@ def main():
         default_deadline_s=args.deadline_ms / 1e3,
     ))
     rt.warmup(kinds=("count", "topk"), batch_sizes=(args.batch,))
-    # realistic warm waves settle the grow-only brute windows (each growth
-    # recompiles the bucket) so the timed loop below is steady-state
+    # realistic warm waves compile the workload's own pattern-length
+    # buckets (the probe above is one symbol long), so the timed loop
+    # below is steady-state
     warm_rng = np.random.default_rng(1)
     for kind in ("count", "topk"):
         for _ in range(2):

@@ -77,8 +77,8 @@ def main():
             return [workload[i], workload[int(j)]]
         return workload[i]
 
-    # warm pass: compiles the (mode, bucket) program and settles the
-    # grow-only brute windows outside the timed (and deadlined) loop
+    # warm pass: compiles the (mode, bucket) program outside the timed
+    # (and deadlined) loop
     for _ in range(2):
         rt.serve([(args.mode, payload(int(i)))
                   for i in rng.integers(0, len(workload), args.batch)],

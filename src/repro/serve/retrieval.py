@@ -19,9 +19,11 @@ Execution architecture — a three-stage on-device engine:
    ONE fused Pallas backward-search launch per batch on TPU
    (repro.kernels.backward_search; backend auto-detected) and as the
    pair-descent XLA program elsewhere — both bit-identical to the
-   reference.  Planner occ stats also size the Brute-L locate window per
-   compile bucket (dispatch-aware, grow-only powers of two), replacing the
-   static max_buf window.
+   reference.  The Brute-L locate window is derived on the host from the
+   dispatch rule itself (``_brute_window_for``): auto dispatch never sends
+   a range of ``threshold * d`` or more occurrences to Brute-L, so that
+   bound, clamped to ``max_buf``, is one static window per service — no
+   planner round trip sizes it and traffic never recompiles it.
 2. **Masked batch executors** (repro.core.{listing,ilcp,pdl,tfidf}):
    vmapped fixed-shape ``*_batch`` entry points.  Every engine runs over
    the full batch with the queries not assigned to it collapsed to empty
@@ -108,29 +110,10 @@ def _bucket_len(m: int) -> int:
     return max(8, -(-m // 8) * 8)
 
 
-#: smallest dispatch-aware Brute-L window; windows grow in powers of two up
-#: to the endpoint's ``max_buf``, so each bucket recompiles at most
-#: lg(max_buf / floor) times as traffic reveals larger brute ranges.
-BRUTE_WINDOW_FLOOR = 32
-
 #: largest servable pattern-length bucket.  Patterns longer than this never
 #: reach the device: ``normalize_patterns`` collapses them to empty queries
 #: (empty results), so one absurd request cannot force a giant compile.
 MAX_PATTERN_LEN = 4096
-
-
-def _pow2_ceil(x: int) -> int:
-    return 1 if x <= 1 else 1 << (x - 1).bit_length()
-
-
-_ENGINE_NAMES = {ENGINE_EMPTY: "empty", ENGINE_BRUTE: "brute",
-                 ENGINE_ILCP: "ilcp", ENGINE_PDL: "pdl"}
-
-
-def _engine_rows(engine) -> dict:
-    """Rows of a plan by the engine it assigned them to."""
-    counts = np.bincount(engine, minlength=len(_ENGINE_NAMES))
-    return {name: int(counts[code]) for code, name in _ENGINE_NAMES.items()}
 
 
 def _to_device(*arrays):
@@ -172,8 +155,8 @@ def _list_program(
 ):
     """list_docs as one program: plan, run all engines masked, select.
 
-    ``brute_win`` is the Brute-L locate window — sized per compile bucket
-    from planner occ stats (dispatch-aware), not the static ``max_buf``.
+    ``brute_win`` is the Brute-L locate window
+    (``RetrievalService._brute_window_for``), at most ``max_buf``.
     ``use_list_kernel`` selects the ILCP executor's backend: the fused
     Pallas listing kernel (one launch — the program's second, after the
     planner's backward search) or the XLA vmap'd while_loop.
@@ -288,9 +271,8 @@ class RetrievalService:
     occ_df_threshold: float = 4.0     # paper: brute wins when occ/df < ~4
     use_search_kernel: bool = False   # fused Pallas backward search runs
     use_list_kernel: bool = False     # fused Pallas ILCP listing runs
-    brute_window: int | None = None   # None = size per bucket from occ stats
+    brute_window: int | None = None   # None = derive from threshold and d
     _cache: dict = dataclasses.field(default_factory=dict, repr=False)
-    _brute_windows: dict = dataclasses.field(default_factory=dict, repr=False)
     compile_counts: dict = dataclasses.field(default_factory=dict, repr=False)
     #: per-structure CRC32s recorded by build-time validation (``repro.
     #: serve.validate``); a load path compares them via verify_fingerprints
@@ -416,31 +398,25 @@ class RetrievalService:
             sp.set(rows=B, padded_rows=pats.shape[0])
             return (pats, lens, *self._knobs(engine), B)
 
-    def _brute_window_for(self, kind: str, bucket_key: tuple, patterns,
-                          engine: str, max_buf: int) -> int:
-        """Dispatch-aware Brute-L window (ROADMAP item): sized per compile
-        bucket from the planner's occ stats instead of the static
-        ``max_buf``.
+    def _brute_window_for(self, engine: str, max_buf: int) -> int:
+        """Static Brute-L window of a ``list`` / ``topk`` program: the
+        pinned ``brute_window`` if any, else one that holds every range
+        the planner can send to Brute-L, clamped to ``max_buf``.
 
-        The plan pass is one (cached) compiled program; the window is the
-        power-of-two cover of the largest occ among brute-assigned queries,
-        clamped to [BRUTE_WINDOW_FLOOR, max_buf], and grows monotonically
-        per bucket so recompiles are bounded by lg(max_buf).  Results are
-        unchanged: the brute executor masks the window against each query's
-        true occ, and queries past max_buf truncate exactly as the
-        reference path does."""
+        Auto dispatch sends a row to Brute-L iff ``occ < threshold *
+        max(df, 1)`` in float32 (``plan_queries``), and ``df <= d``, so no
+        occ at or above ``ceil(float32(threshold) * float32(d))`` goes to
+        Brute-L; the product is taken in float32 here too, so the edge
+        agrees with the device bit for bit.  A forced ``"brute"`` sends
+        every row, of any occ, to Brute-L: its window is ``max_buf``, where
+        the reference path truncates too."""
         if self.brute_window is not None:
             return min(self.brute_window, max_buf)
-        with obs.span("serve.window_plan") as sp:
-            plan = self.plan(patterns, engine)
-            sp.set(engine_rows=lambda: _engine_rows(plan["engine"]))
-        occ = plan["occ"][plan["engine"] == ENGINE_BRUTE]
-        needed = int(occ.max()) if occ.size else 0
-        win = min(max(_pow2_ceil(needed), BRUTE_WINDOW_FLOOR), max_buf)
-        key = (kind, bucket_key)
-        win = max(win, self._brute_windows.get(key, 0))
-        self._brute_windows[key] = win
-        return win
+        if engine == "brute":
+            return max_buf
+        edge = np.float32(self.occ_df_threshold) * np.float32(max(self.coll.d, 1))
+        cover = int(np.ceil(edge)) if np.isfinite(edge) else max_buf
+        return min(max(cover, 1), max_buf)
 
     # -- planned endpoints (single compiled program per shape bucket) --------
 
@@ -492,9 +468,7 @@ class RetrievalService:
         if not len(patterns):
             return np.zeros((0, max_df), np.int32), np.zeros(0, np.int32)
         pats, lens, thresh, forced, B = self._pack(patterns, engine)
-        win = self._brute_window_for(
-            "list", (pats.shape, max_df, max_buf), patterns, engine, max_buf
-        )
+        win = self._brute_window_for(engine, max_buf)
         faults.fire("executor:list")
         args = (self.csa, self.ilcp, self.pdl_list, self.da, self.sada,
                 pats, lens, thresh, forced)
@@ -506,7 +480,7 @@ class RetrievalService:
             ),
             args,
         )
-        with obs.span("serve.run"):
+        with obs.span("serve.run", brute_window=win):
             docs, cnt, _plan = exe(*args)
             out = _to_host((docs, cnt), B)
         return faults.poison("executor:list", out)
@@ -532,9 +506,7 @@ class RetrievalService:
             return np.zeros((0, k), np.int32), np.zeros((0, k), np.int32)
         pats, lens, thresh, forced, B = self._pack(patterns, engine)
         max_df = self._topk_max_df(max_buf)
-        win = self._brute_window_for(
-            "topk", (pats.shape, k, max_buf), patterns, engine, max_buf
-        )
+        win = self._brute_window_for(engine, max_buf)
         faults.fire("executor:topk")
         args = (self.csa, self.pdl_topk, self.sada, pats, lens, thresh, forced)
         exe = self._compiled(
@@ -544,7 +516,7 @@ class RetrievalService:
             ),
             args,
         )
-        with obs.span("serve.run"):
+        with obs.span("serve.run", brute_window=win):
             docs, tfs, _plan = exe(*args)
             out = _to_host((docs, tfs), B)
         return faults.poison("executor:topk", out)
@@ -740,7 +712,7 @@ class RetrievalService:
                 return (self.csa, self.sada) + self._audit_batch(B, m)
         elif kind == "list":
             fn = functools.partial(
-                _list_program, max_df, min(BRUTE_WINDOW_FLOOR, max_buf),
+                _list_program, max_df, self._brute_window_for("auto", max_buf),
                 max_buf, use_kernel, use_list_kernel,
             )
 
@@ -750,7 +722,7 @@ class RetrievalService:
         elif kind == "topk":
             fn = functools.partial(
                 _topk_program, k, self._topk_max_df(max_buf),
-                min(BRUTE_WINDOW_FLOOR, max_buf), max_buf, use_kernel,
+                self._brute_window_for("auto", max_buf), max_buf, use_kernel,
             )
 
             def args(B, m):

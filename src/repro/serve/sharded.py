@@ -80,15 +80,13 @@ from repro.dist.sharding import (
     docs_mesh_size,
 )
 from repro.serve import faults
-from repro.serve.planner import ENGINE_BRUTE, ENGINE_CODES, plan_queries
+from repro.serve.planner import ENGINE_CODES, plan_queries
 from repro.serve.retrieval import (
-    BRUTE_WINDOW_FLOOR,
     MAX_PATTERN_LEN,
     RetrievalService,
     _bucket_batch,
     _bucket_len,
     _list_program,
-    _pow2_ceil,
     _topk_program,
     kernel_selection,
 )
@@ -345,7 +343,6 @@ class ShardedRetrievalService:
     use_list_kernel: bool = False
     brute_window: int | None = None
     _cache: dict = dataclasses.field(default_factory=dict, repr=False)
-    _brute_windows: dict = dataclasses.field(default_factory=dict, repr=False)
     compile_counts: dict = dataclasses.field(default_factory=dict, repr=False)
     fingerprints: dict = dataclasses.field(default_factory=dict, repr=False)
 
@@ -446,21 +443,10 @@ class ShardedRetrievalService:
             jnp.int32(ENGINE_CODES[engine]),
         )
 
-    def _brute_window_for(self, kind, bucket_key, patterns, engine, max_buf):
-        """One Brute-L window shared by every shard, sized from the largest
-        brute-assigned *per-shard* occ (grow-only, as in the single-device
-        cache)."""
-        if self.brute_window is not None:
-            return min(self.brute_window, max_buf)
-        plan = self.plan(patterns, engine)
-        occ_sb = plan["hi"] - plan["lo"]                 # [S, B] shard-local
-        brute = occ_sb[plan["engine_shard"] == ENGINE_BRUTE]
-        needed = int(brute.max()) if brute.size else 0
-        win = min(max(_pow2_ceil(needed), BRUTE_WINDOW_FLOOR), max_buf)
-        key = (kind, bucket_key)
-        win = max(win, self._brute_windows.get(key, 0))
-        self._brute_windows[key] = win
-        return win
+    #: one Brute-L window shared by every shard, from the global ``d``: each
+    #: shard dispatches on its own occ and df, and both are at most the
+    #: global ones, so the flat service's cover bounds every shard's rows
+    _brute_window_for = RetrievalService._brute_window_for
 
     # -- endpoints -----------------------------------------------------------
 
@@ -503,9 +489,7 @@ class ShardedRetrievalService:
             return np.zeros((0, max_df), np.int32), np.zeros(0, np.int32)
         pats, lens, B = self._pad_batch(patterns)
         thresh, forced = self._knobs(engine)
-        win = self._brute_window_for(
-            "list", (pats.shape, max_df, max_buf), patterns, engine, max_buf
-        )
+        win = self._brute_window_for(engine, max_buf)
         faults.fire("executor:list")
         args = (_shard_args(self.shards), pats, lens, thresh, forced)
         exe = self._compiled(
@@ -537,9 +521,7 @@ class ShardedRetrievalService:
         pats, lens, B = self._pad_batch(patterns)
         thresh, forced = self._knobs(engine)
         max_df = self._topk_max_df(max_buf)
-        win = self._brute_window_for(
-            "topk", (pats.shape, k, max_buf), patterns, engine, max_buf
-        )
+        win = self._brute_window_for(engine, max_buf)
         faults.fire("executor:topk")
         args = (_shard_args(self.shards), pats, lens, thresh, forced)
         exe = self._compiled(
@@ -719,7 +701,7 @@ class ShardedRetrievalService:
         elif kind == "list":
             fn = functools.partial(
                 _sharded_list_program, self.mesh, bases, max_df,
-                min(BRUTE_WINDOW_FLOOR, max_buf), max_buf, use_kernel,
+                self._brute_window_for("auto", max_buf), max_buf, use_kernel,
                 use_list_kernel,
             )
 
@@ -728,8 +710,8 @@ class ShardedRetrievalService:
         elif kind == "topk":
             fn = functools.partial(
                 _sharded_topk_program, self.mesh, bases, k,
-                self._topk_max_df(max_buf), min(BRUTE_WINDOW_FLOOR, max_buf),
-                max_buf, use_kernel,
+                self._topk_max_df(max_buf),
+                self._brute_window_for("auto", max_buf), max_buf, use_kernel,
             )
 
             def args(B, m):

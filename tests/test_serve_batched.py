@@ -14,7 +14,7 @@ from repro.serve.planner import (
     ENGINE_ILCP,
     ENGINE_PDL,
 )
-from repro.serve.retrieval import BRUTE_WINDOW_FLOOR, RetrievalService
+from repro.serve.retrieval import RetrievalService
 
 MAX_BUF = 512
 
@@ -155,8 +155,7 @@ def test_one_compile_per_bucket():
         SyntheticSpec("version", n_base=2, n_variants=5, base_len=80,
                       mutation_rate=0.01, seed=11)
     )
-    # brute_window pinned: the dispatch-aware auto window is allowed its own
-    # (bounded) recompiles and has a dedicated test below
+    # brute_window pinned: the derived window has dedicated tests below
     svc = RetrievalService.build(
         coll, block_size=16, beta=8.0, brute_window=MAX_BUF
     )
@@ -204,46 +203,130 @@ def test_one_compile_per_bucket():
     assert svc.compile_counts["tfidf"] == 1
 
 
-def test_auto_brute_window():
-    """Dispatch-aware Brute-L window: sized per compile bucket from planner
-    occ stats, power-of-two, clamped to [floor, max_buf], grow-only — and
-    results stay bit-identical to the reference path."""
+def _least_cover(threshold, d):
+    """Least integer occ that the planner's float32 rule does not send to
+    Brute-L at df = d, found by walking up from 0."""
+    edge = np.float32(threshold) * np.float32(max(d, 1))
+    w = 0
+    while np.float32(w) < edge:
+        w += 1
+    return w
+
+
+def _ran_window(svc, kind):
+    """The Brute-L windows of the ``kind`` programs in the compile cache
+    (the static just before ``max_buf``)."""
+    return {statics[-2] for k, statics in svc._cache if k == kind}
+
+
+def _served(svc, kind, pats, engine):
+    if kind == "list":
+        return svc.list_docs(pats, max_df=32, engine=engine, max_buf=MAX_BUF)
+    return svc.topk(pats, k=4, engine=engine, max_buf=MAX_BUF)
+
+
+@pytest.fixture(scope="module")
+def window_svc():
+    # 8 documents: a power of two, so that the edge test's thresholds and
+    # float32 edges are exact
     coll = generate(
-        SyntheticSpec("version", n_base=2, n_variants=5, base_len=80,
+        SyntheticSpec("version", n_base=2, n_variants=4, base_len=80,
                       mutation_rate=0.01, seed=11)
     )
     svc = RetrievalService.build(coll, block_size=16, beta=8.0)
+    pats = random_substring_patterns(coll, 100, 3, 12)
+    assert len(pats) >= 6
+    return svc, pats
+
+
+@pytest.mark.parametrize("engine", ["auto", "brute"])
+@pytest.mark.parametrize("kind", ["list", "topk"])
+def test_auto_brute_window(window_svc, kind, engine):
+    """The Brute-L window is derived from the threshold and d, not from a
+    plan of the batch: min(max_buf, least cover) for auto dispatch,
+    max_buf for forced brute; answers stay bit-identical to the reference
+    path, and another batch of the same bucket compiles nothing."""
+    svc, pats = window_svc
     assert svc.brute_window is None
-    pats = random_substring_patterns(coll, 100, 4, 12)
-    assert len(pats) >= 9
+    want = (MAX_BUF if engine == "brute"
+            else min(MAX_BUF, _least_cover(svc.occ_df_threshold, svc.coll.d)))
+    if engine == "auto":
+        assert (svc.plan(pats[:5])["engine"] == ENGINE_BRUTE).any()
+    ref = "reference" if engine == "auto" else "reference:brute"
+    assert _served(svc, kind, pats[:5], engine) == \
+        _served(svc, kind, pats[:5], ref)
+    assert want in _ran_window(svc, kind)
+    assert svc._brute_window_for(engine, MAX_BUF) == want
 
-    got = svc.list_docs(pats[:8], max_df=32, max_buf=MAX_BUF)
-    ref = svc.list_docs(pats[:8], max_df=32, engine="reference",
-                        max_buf=MAX_BUF)
-    assert got == ref
-    wins = list(svc._brute_windows.values())
-    assert wins, "auto window was never recorded"
-    assert all(w & (w - 1) == 0 for w in wins), "windows must be powers of 2"
-    assert all(BRUTE_WINDOW_FLOOR <= w <= MAX_BUF for w in wins)
+    # batches of 5 and 6 share the bucket of 8
+    compiles, cached = dict(svc.compile_counts), len(svc._cache)
+    assert _served(svc, kind, pats[:6], engine) == \
+        _served(svc, kind, pats[:6], ref)
+    assert svc.compile_counts == compiles and len(svc._cache) == cached
 
-    # grow-only per bucket: a lighter batch in the same bucket never shrinks
-    # the window (so it never recompiles downward)
-    before = dict(svc._brute_windows)
-    compiles = svc.compile_counts.get("list", 0)
-    svc.list_docs(pats[1:9], max_df=32, max_buf=MAX_BUF)
-    for key, win in before.items():
-        assert svc._brute_windows[key] >= win
-    assert svc.compile_counts["list"] <= compiles + 1
 
-    # forcing brute routes every nonempty query through the sized window;
-    # parity with the reference loop proves the window never truncates
-    gb = svc.list_docs(pats[:8], max_df=32, engine="brute", max_buf=MAX_BUF)
-    rb = svc.list_docs(pats[:8], max_df=32, engine="reference:brute",
-                       max_buf=MAX_BUF)
-    assert gb == rb
-    gt = svc.topk(pats[:8], k=4, engine="brute", max_buf=MAX_BUF)
-    rt = svc.topk(pats[:8], k=4, engine="reference:brute", max_buf=MAX_BUF)
-    assert gt == rt
+@pytest.mark.parametrize("edge", ["below", "at"])
+def test_brute_window_float32_edge(window_svc, edge, monkeypatch):
+    """A pattern in every document whose occ sits at threshold * df - 1
+    (Brute-L, inside the window) or at threshold * df (PDL): the derived
+    window is the planner's own float32 edge, and list and top-k stay
+    bit-identical to the reference path."""
+    svc, pats = window_svc
+    d = svc.coll.d
+    plan = svc.plan(pats)
+    every = [i for i in range(len(pats)) if plan["df"][i] == d]
+    assert every, "no pattern occurs in every document"
+    top = max(every, key=lambda i: plan["occ"][i])
+    occ = int(plan["occ"][top])
+    assert d & (d - 1) == 0
+    threshold = (occ + 1) / d if edge == "below" else occ / d
+    monkeypatch.setattr(svc, "occ_df_threshold", threshold)
+    (engine,) = svc.plan([pats[top]])["engine"]
+    assert engine == (ENGINE_BRUTE if edge == "below" else ENGINE_PDL)
+    win = svc._brute_window_for("auto", MAX_BUF)
+    assert win == _least_cover(threshold, d) == (occ + 1 if edge == "below"
+                                                 else occ)
+    batch = [pats[top]] + pats[:4]
+    for kind in ("list", "topk"):
+        assert _served(svc, kind, batch, "auto") == \
+            _served(svc, kind, batch, "reference")
+        assert win in _ran_window(svc, kind)
+
+
+@pytest.mark.parametrize("sharded", [
+    pytest.param(False, id="flat"),
+    pytest.param(True, id="sharded", marks=pytest.mark.skipif(
+        jax.device_count() < 4, reason="the docs mesh needs >= 4 devices")),
+])
+def test_list_and_topk_run_no_plan_program(sharded, monkeypatch):
+    """A ``list`` or ``topk`` call runs one program, its own: the Brute-L
+    window needs no plan of the batch, flat or on a docs mesh."""
+    coll = generate(
+        SyntheticSpec("version", n_base=2, n_variants=4, base_len=60,
+                      mutation_rate=0.01, seed=3)
+    )
+    mesh = None
+    if sharded:
+        from repro.dist.sharding import make_docs_mesh
+
+        mesh = make_docs_mesh(4)
+    svc = RetrievalService.build(coll, block_size=16, beta=8.0,
+                                 validate=False, mesh=mesh)
+    pats = random_substring_patterns(coll, 20, 4, 8)
+    cls = type(svc)
+
+    def no_plan(*a, **kw):
+        raise AssertionError("a list/topk call planned its batch")
+
+    ran = []
+    compiled = cls._compiled
+    monkeypatch.setattr(cls, "plan", no_plan)
+    monkeypatch.setattr(cls, "_compiled", lambda self, kind, *a:
+                        ran.append(kind) or compiled(self, kind, *a))
+    svc.list_docs(pats[:3], max_df=coll.d + 1, max_buf=MAX_BUF)
+    svc.topk(pats[:3], k=3, max_buf=MAX_BUF)
+    assert ran == ["list", "topk"]
+    assert svc.compile_counts == {"list": 1, "topk": 1}
 
 
 def test_empty_batch():

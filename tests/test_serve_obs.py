@@ -11,7 +11,6 @@ import re
 import time
 
 import jax
-import numpy as np
 import pytest
 from jax._src.lib import _profiler
 
@@ -95,31 +94,21 @@ def test_a_list_batch_of_three_splits_into_nested_stages(served):
         "kind": "list", "rows": 3, "bucket": 4}
     (call,) = by_name["serve.call"]
     assert call[3]["path"] == "full" and _inside(call, st)
-    (wp,) = by_name["serve.window_plan"]
-    packs, runs = by_name["serve.pack"], by_name["serve.run"]
-    assert len(packs) == len(runs) == 2
-    # the window plan holds a pack and a run of its own; the endpoint's
-    # pack, window plan, run and unpack follow each other inside the call
-    inner_pack, inner_run = ([e for e in packs if _inside(e, wp)],
-                             [e for e in runs if _inside(e, wp)])
-    assert len(inner_pack) == len(inner_run) == 1
-    (pack,) = [e for e in packs if not _inside(e, wp)]
-    (run,) = [e for e in runs if not _inside(e, wp)]
+    assert "serve.window_plan" not in by_name
+    (pack,) = by_name["serve.pack"]
+    (run,) = by_name["serve.run"]
     (unpack,) = by_name["serve.unpack"]
-    stages = [pack, wp, run, unpack]
+    # one pack, one program run and one unpack follow each other inside
+    # the call: the Brute-L window needs no plan of its own
+    stages = [pack, run, unpack]
     assert all(_inside(e, call) for e in stages)
     assert all(a[2] <= b[1] for a, b in zip(stages, stages[1:]))
     assert pack[3]["rows"] == 3 and pack[3]["padded_rows"] == 4
-    # transfers: patterns, lengths and two knobs put per pack; five plan
-    # arrays and two endpoint outputs fetched
-    assert pack[3]["transfers"] == inner_pack[0][3]["transfers"] == 4
-    assert inner_run[0][3]["transfers"] == 5 and run[3]["transfers"] == 2
-    assert wp[3]["transfers"] == 9
-    assert st[3]["transfers"] == call[3]["transfers"] == 15
-    engine = svc.plan(pats)["engine"]
-    names = {0: "empty", 1: "brute", 2: "ilcp", 3: "pdl"}
-    assert wp[3]["engine_rows"] == {
-        name: int(np.sum(engine == code)) for code, name in names.items()}
+    assert run[3]["brute_window"] == svc._brute_window_for(
+        "auto", rt.config.max_buf)
+    # transfers: patterns, lengths and two knobs put; two outputs fetched
+    assert pack[3]["transfers"] == 4 and run[3]["transfers"] == 2
+    assert st[3]["transfers"] == call[3]["transfers"] == 6
 
 
 def test_a_cache_miss_emits_a_compile_span(served):
@@ -137,8 +126,8 @@ def test_span_names_keep_clear_of_the_benchmarks_own():
     names = set()
     for path in SERVE.glob("*.py"):
         names |= set(re.findall(r'obs\.span\(\s*"([^"]+)"', path.read_text()))
-    assert {"serve.step", "serve.call", "serve.pack", "serve.window_plan",
-            "serve.run", "serve.compile", "serve.unpack"} <= names
+    assert {"serve.step", "serve.call", "serve.pack", "serve.run",
+            "serve.compile", "serve.unpack"} <= names
     assert all(n.startswith("serve.") for n in names)
     assert not names & set(devtrace.SPANS)
 

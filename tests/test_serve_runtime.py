@@ -125,11 +125,17 @@ def test_poisoned_payload_never_reaches_caller(svc_pats):
 
 def test_planner_and_compile_faults_degrade(svc_pats):
     svc, pats = svc_pats
+    # the planner site guards the plan program, which count runs; a list
+    # program plans on the device, so its buckets are made cold here for
+    # the compile fault to reach it
+    for key in [k for k in svc._cache if k[0] == "list"]:
+        del svc._cache[key]
     specs = parse_fault_specs("planner_fail:1.0,compile_error:1.0")
     rt = _runtime(svc, max_retries=0)
-    with faults.inject(*specs):
+    with faults.inject(*specs) as inj:
         answers = rt.serve([("count", pats[0]), ("list", pats[1])])
     assert all(a.degraded for a in answers)
+    assert {"plan", "compile:list"} <= {site for site, _, _ in inj.fired}
     assert answers[0].result == int(
         svc.count([pats[0]], engine="reference")[0]
     )
