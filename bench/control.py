@@ -33,7 +33,7 @@ sys.path[:0] = [str(ROOT)]
 import ml_dtypes  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import check, corpus, harness, spec  # noqa: E402
+from bench import check, harness, spec  # noqa: E402
 from bench.reference import Reference  # noqa: E402
 
 TRUNCATE = 256
@@ -65,8 +65,7 @@ def answer(ref: Reference, kind: str, payload, k: int):
 def control_checks(cell: spec.Cell, seed: int, seconds: float) -> dict:
     """The numbers compared when the control answers one run's requests."""
     cfg, traffic = cell.config, cell.traffic
-    docs = corpus.generate(cfg, harness._rng(cfg["data_seed"], harness._CORPUS))
-    ref = Reference(docs, len(cfg["alphabet"]))
+    ref = Reference(*harness.collection(cell))
     pool = cell.module("pools", traffic["pool"]["kind"]).make(
         ref, traffic["pool"], harness._rng(seed, harness._POOL))
     src = cell.module("arrivals", traffic["arrivals"]["kind"]).make(

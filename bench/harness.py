@@ -2,8 +2,8 @@
 
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the configuration's collection with the benchmark's own
-generator (``corpus.py``), hands it to the program through
+Set-up builds the configuration's collection with the corpus plug-in that
+it names (``corpora/<corpus>.py``), hands it to the program through
 ``repro.core.suffix.concat_documents``, builds ``RetrievalService`` and
 ``ServeRuntime`` over it, draws the pattern pool and warms every (endpoint
 kind x batch bucket x pattern length) that the window can cut.  The window
@@ -46,6 +46,19 @@ _CORPUS, _POOL, _TRAFFIC, _REQUESTS = 0, 1, 2, 3
 
 def _rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([seed, stream])
+
+
+def collection(cell: spec.Cell) -> tuple[list[np.ndarray], int]:
+    """The configuration's raw documents and the size of their alphabet,
+    from its corpus plug-in and its fixed ``data_seed``.
+
+    The collection is the deployment's, not the run's: the index's compiled
+    shapes follow the data, so a collection drawn from --seed would compile
+    every program anew on every seed."""
+    cfg = cell.config
+    corpus_kind = cell.module("corpora", cfg["corpus"])
+    docs = corpus_kind.generate(cfg, _rng(cfg["data_seed"], _CORPUS))
+    return docs, corpus_kind.alphabet_size(cfg)
 
 
 class NoChip(RuntimeError):
@@ -167,11 +180,8 @@ class Session:
 
         self.cell, self.seed, self.t_start = cell, seed, t_start
         cfg, traffic = cell.config, cell.traffic
-        # the collection is the deployment's, fixed by the configuration:
-        # the index's compiled shapes follow the data, so a collection drawn
-        # from --seed would compile every program anew on every seed
-        docs = corpus.generate(cfg, _rng(cfg["data_seed"], _CORPUS))
-        self.ref = Reference(docs, len(cfg["alphabet"]))
+        docs, alphabet_size = collection(cell)
+        self.ref = Reference(docs, alphabet_size)
         self.coll = concat_documents(docs)
         self.svc = RetrievalService.build(self.coll, **cfg["service"])
         pool_spec = traffic["pool"]

@@ -8,23 +8,18 @@ the per-layer readers ``metrics/<stage>_ms_per_step.py`` and their kin
 reduce the groups.
 
 Stages are the spans that split a call: a stage inside another stage
-(the ``serve.pack`` and ``serve.run`` of a ``serve.window_plan``) counts
-toward the outer one only.  Steps are those that executed a batch and lie
-wholly inside the window.  Everything returns ``None`` when the program
-recorded nothing there (no ``repro.serve.obs``, or no profiler session) or
-dropped any event of the window, so a reader never reports a partial
-count.
+counts toward the outer one only.  Steps are those that executed a batch
+and lie wholly inside the window.  Everything returns ``None`` when the
+program recorded nothing there (no ``repro.serve.obs``, or no profiler
+session) or dropped any event of the window, so a reader never reports a
+partial count.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-STAGES = ("serve.pack", "serve.window_plan", "serve.run", "serve.unpack",
-          "serve.compile")
-
-#: executors that a batch of each kind runs over every padded row
-EXECUTORS = {"list": 3, "topk": 2}
+STAGES = ("serve.pack", "serve.run", "serve.unpack", "serve.compile")
 
 
 @dataclasses.dataclass
@@ -52,12 +47,6 @@ class Step:
 
     def calls(self) -> float:
         return sum(e[2] - e[1] for e in self.events if e[0] == "serve.call")
-
-    def top_level(self, name: str) -> list:
-        return [e for e in self._top_level() if e[0] == name]
-
-    def inside(self, name: str) -> list:
-        return [e for e in self.events if e[0] == name]
 
 
 def group(events, window) -> list[Step]:
@@ -92,20 +81,3 @@ def mean_per_step(run, per_step) -> float | None:
     if found is None:
         return None
     return sum(per_step(s) for s in found) / len(found)
-
-
-def useful_row_share(found: list[Step]) -> float | None:
-    """Over ``list`` and ``topk`` steps: rows that the window plan assigned
-    to an engine, over the rows that the executors ran (each executor runs
-    every padded row)."""
-    useful = ran = 0
-    for s in found:
-        n_exec = EXECUTORS.get(s.attrs.get("kind"))
-        plans = s.inside("serve.window_plan")
-        packs = s.top_level("serve.pack")
-        if n_exec is None or not plans or len(plans) != len(packs):
-            continue
-        useful += sum(n for p in plans
-                      for eng, n in p[3]["engine_rows"].items() if eng != "empty")
-        ran += n_exec * sum(p[3]["padded_rows"] for p in packs)
-    return useful / ran if ran else None

@@ -1,9 +1,12 @@
 """Plain reference for the four retrieval endpoints, over the raw documents.
 
-It finds a pattern's occurrences by direct string matching (a k-mer table
-narrows the candidate positions, each candidate is then compared symbol by
-symbol) and answers from those positions alone.  It imports nothing of the
-program and uses nothing the program built:
+It finds a pattern's occurrences by direct string matching and answers
+from those positions alone.  A table of the text's k-mers, each keyed by its
+exact value in base ``alphabet_size + 1``, narrows the candidates to those
+that match the pattern's first ``min(m, k)`` symbols; each is then compared
+on the rest, symbol by symbol.  k is the longest, up to ``KMAX``, whose keys
+fit in int64 (``base**k < 2**63``): 8 at base 5, 7 at base 257 (bytes).  It
+imports nothing of the program and uses nothing the program built:
 
 * ``count``: df, the number of documents that hold the pattern;
 * ``list``: every such document, ascending;
@@ -12,7 +15,8 @@ program and uses nothing the program built:
 * ``tfidf``: documents ranked by sum_t tf(D, t) * lg(d / max(df_t, 1)),
   score descending and doc ascending (ranked OR, or ranked AND), in float64.
 
-Patterns are raw symbol arrays, as ``corpus.generate`` makes documents.
+Patterns are raw symbol arrays, as a corpus plug-in's ``generate`` makes
+documents.
 """
 
 from __future__ import annotations
@@ -23,10 +27,17 @@ import numpy as np
 KMAX = 8
 
 
+def key_length(base: int) -> int:
+    """The longest k up to ``KMAX`` whose base-``base`` keys are exact in
+    int64."""
+    return max(k for k in range(1, KMAX + 1) if base ** k < 2 ** 63)
+
+
 class Reference:
     def __init__(self, docs: list[np.ndarray], alphabet_size: int, pad: int = 4096):
         self.d = len(docs)
         self.base = alphabet_size + 1          # the separator is ``alphabet_size``
+        self.kmax = key_length(self.base)
         sep = np.full(1, alphabet_size, np.int64)
         parts = []
         for doc in docs:
@@ -52,9 +63,9 @@ class Reference:
         """Ascending text positions where ``pattern`` starts."""
         pat = np.asarray(pattern, np.int64)
         m = len(pat)
-        if m == 0:
-            return np.zeros(0, np.int64)
-        k = min(m, KMAX)
+        if m == 0 or pat.min() < 0 or pat.max() >= self.base - 1:
+            return np.zeros(0, np.int64)       # empty, or not of the alphabet
+        k = min(m, self.kmax)
         codes, order = self._table(k)
         key = 0
         for s in pat[:k]:

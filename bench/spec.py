@@ -1,9 +1,12 @@
 """Finding a cell's parts by name.
 
 ``BENCHMARK.json`` names each cell's configuration and traffic mix.  A
-configuration is the JSON file that its entry names; a traffic mix is
-``<bench>/traffic/<name>.json``; the arrival process and the pattern pool
-that a mix names are ``<bench>/arrivals/<kind>.py`` and
+configuration is the JSON file that its entry names; the collection it
+names under ``corpus`` is ``<bench>/corpora/<corpus>.py``, a module with
+``generate(config, rng) -> list[np.ndarray]`` (raw documents in
+``[0, alphabet_size(config))``) and ``alphabet_size(config) -> int``; a
+traffic mix is ``<bench>/traffic/<name>.json``; the arrival process and the
+pattern pool that a mix names are ``<bench>/arrivals/<kind>.py`` and
 ``<bench>/pools/<kind>.py``; each metric is ``<bench>/metrics/<name>.py``,
 a module with ``read(run) -> float | None``.  A later cell adds files and a
 ``workloads`` entry, and edits none of these.

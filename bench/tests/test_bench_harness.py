@@ -71,19 +71,59 @@ def read(run):
 '''
 
 
+UTF8_WORDS = '''
+"""Documents of words in UTF-8, as raw bytes (a test's own collection)."""
+import numpy as np
+
+WORDS = ("äitiään", "ja", "isä", "sääntö", "öljyä", "yö", "tänään", "€uro",
+         "Åbo", "kő", "hyvää", "päivää")
+
+
+def alphabet_size(config):
+    return 256
+
+
+def generate(config, rng):
+    return [np.frombuffer(" ".join(rng.choice(WORDS, config["n_words"])).encode(),
+                          np.uint8).astype(np.int64)
+            for _ in range(config["n_docs"])]
+'''
+
+BYTE_SPANS = '''
+"""Spans of the text in several lengths, every other one starting at a byte
+of 0x80 or above (a test's own pool)."""
+import numpy as np
+
+
+def make(ref, params, rng):
+    lengths, pats = params["lengths"], []
+    while len(pats) < int(params["size"]):
+        m = lengths[len(pats) % len(lengths)]
+        p = int(rng.integers(0, ref.n - m))
+        sub = ref.text[p: p + m]
+        if (sub == ref.base - 1).any() or (sub[0] >= 0x80) != len(pats) % 2:
+            continue
+        pats.append(sub.astype(np.int32))
+    return pats
+'''
+
+
 @pytest.fixture(scope="module")
 def bench_dir(tmp_path_factory):
     """A benchmark tree of its own: the harness's plug-ins, plus a new
-    configuration, traffic mix, arrival process, pool and metric."""
+    configuration, traffic mix, arrival process, pool and metric, and a
+    configuration that brings its own collection of UTF-8 bytes."""
     root = tmp_path_factory.mktemp("benchroot")
     b = root / "b"
-    for group in ("arrivals", "pools", "metrics"):
+    for group in ("corpora", "arrivals", "pools", "metrics"):
         shutil.copytree(BENCH / group, b / group)
     (b / "configs").mkdir()
     (b / "traffic").mkdir()
     (b / "arrivals" / "steady.py").write_text(STEADY)
     (b / "pools" / "first_substrings.py").write_text(FIRST_SUBSTRINGS)
     (b / "metrics" / "answered.py").write_text(ANSWERED)
+    (b / "corpora" / "utf8_words.py").write_text(UTF8_WORDS)
+    (b / "pools" / "byte_spans.py").write_text(BYTE_SPANS)
     cfg = json.loads((BENCH / "configs" / "version-p001.json").read_text())
     cfg.update(name="tiny", n_base=3, n_variants=3, base_len=300, mutation_rate=0.02)
     cfg["runtime"].update(max_batch=4, max_df=10, max_buf=512)
@@ -96,13 +136,24 @@ def bench_dir(tmp_path_factory):
                   pool={"kind": "first_substrings", "length": 5, "size": 6},
                   mix={"count": 0.5, "topk": 0.5})
     (b / "traffic" / "steady.json").write_text(json.dumps(steady))
+    words = {"name": "words", "corpus": "utf8_words", "n_docs": 6, "n_words": 60,
+             "data_seed": 0, "service": cfg["service"],
+             "runtime": dict(cfg["runtime"], max_df=7), "checks": cfg["checks"]}
+    (b / "configs" / "words.json").write_text(json.dumps(words))
+    spans = dict(mixed, arrivals={"kind": "steady", "rate": 20.0},
+                 pool={"kind": "byte_spans", "lengths": [8, 12, 16], "size": 12})
+    (b / "traffic" / "spans.json").write_text(json.dumps(spans))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench.update(paths=["b"],
-                 configs=[dict(bench["configs"][0], name="tiny", file="b/configs/tiny.json")],
+                 configs=[dict(bench["configs"][0], name="tiny", file="b/configs/tiny.json"),
+                          dict(bench["configs"][0], name="words",
+                               file="b/configs/words.json")],
                  workloads=[
                      {"name": "tiny.mixed", "config": "tiny", "traffic": "mixed",
                       "chips": 1, "why": "test"},
                      {"name": "tiny.steady", "config": "tiny", "traffic": "steady",
+                      "chips": 1, "why": "test"},
+                     {"name": "words.spans", "config": "words", "traffic": "spans",
                       "chips": 1, "why": "test"}])
     bench["end_to_end"] = [dict(m, workloads=["tiny.mixed"]) if "workloads" in m else m
                            for m in bench["end_to_end"]]
@@ -144,6 +195,22 @@ def test_new_parts_load_from_their_own_files(bench_dir):
     assert result["attempted"] == 20
     assert result["metrics"]["answered"] == {"value": 20.0, "unit": "requests"}
     assert set(result["checks"]) == {"failed", "count_wrong", "topk_wrong"}
+
+
+def test_a_new_collection_needs_files_only(bench_dir):
+    """A corpus plug-in of UTF-8 bytes and a pool of 8 to 16 bytes, added as
+    files: the program serves an alphabet of more than 128 symbols (the
+    largest byte, 0xE2, shifted by one, and the terminator) and every answer
+    agrees with the reference."""
+    cell = spec.load_cell("words.spans", bench_dir / "BENCHMARK.json")
+    sess = harness.Session(cell, 2**31 + 23, time.perf_counter())
+    assert sess.coll.sigma == 0xE2 + 2
+    assert {len(p) for p in sess.pool} == {8, 12, 16}
+    assert sum(int(p[0]) >= 0x80 for p in sess.pool) == 6
+    result = harness.run_cell(_args("words.spans"), t_start=time.perf_counter(),
+                              require_chip=False, session=sess)
+    assert result["correct"], result["checks"]
+    assert result["failed"] == 0 and result["attempted"] == 20
 
 
 def test_sound_run_is_correct(session):

@@ -19,30 +19,24 @@ def _ev(name, a, b, **attrs):
 
 def _events():
     """A list, a count and a top-k step inside the window (0, 100 ms), a
-    step past its end, and a step that found no batch."""
+    step past its end, and a step that found no batch.  The list step's
+    run holds a pack of its own, a stage inside another."""
     return sorted([
-        _ev("serve.step", 10, 30, kind="list", rows=3, bucket=4, transfers=15),
+        _ev("serve.step", 10, 30, kind="list", rows=3, bucket=4, transfers=6),
         _ev("serve.call", 11, 29, path="full"),
-        _ev("serve.pack", 11, 12, rows=3, padded_rows=4, transfers=4),
-        _ev("serve.window_plan", 12, 16, transfers=9,
-            engine_rows={"empty": 0, "brute": 2, "ilcp": 0, "pdl": 1}),
-        _ev("serve.pack", 12, 13, rows=3, padded_rows=4, transfers=4),
-        _ev("serve.run", 13, 16, transfers=5),
-        _ev("serve.run", 16, 27, transfers=2),
+        _ev("serve.pack", 11, 13, rows=3, padded_rows=4, transfers=4),
+        _ev("serve.run", 13, 27, brute_window=40, transfers=2),
+        _ev("serve.pack", 14, 16, rows=3, padded_rows=4, transfers=4),
         _ev("serve.unpack", 27, 29),
         _ev("serve.step", 40, 50, kind="count", rows=2, bucket=2, transfers=9),
         _ev("serve.call", 41, 49, path="full"),
         _ev("serve.pack", 41, 43, rows=2, padded_rows=2, transfers=4),
         _ev("serve.run", 43, 48, transfers=5),
         _ev("serve.unpack", 48, 49),
-        _ev("serve.step", 60, 70, kind="topk", rows=1, bucket=1, transfers=15),
+        _ev("serve.step", 60, 70, kind="topk", rows=1, bucket=1, transfers=6),
         _ev("serve.call", 61, 69, path="full"),
         _ev("serve.pack", 61, 62, rows=1, padded_rows=1, transfers=4),
-        _ev("serve.window_plan", 62, 64, transfers=9,
-            engine_rows={"empty": 0, "brute": 1, "ilcp": 0, "pdl": 0}),
-        _ev("serve.pack", 62, 63, rows=1, padded_rows=1, transfers=4),
-        _ev("serve.run", 63, 64, transfers=5),
-        _ev("serve.run", 64, 68, transfers=2),
+        _ev("serve.run", 62, 68, brute_window=40, transfers=2),
         _ev("serve.unpack", 68, 69),
         _ev("serve.step", 80, 81),
         _ev("serve.step", 95, 105, kind="count", rows=1, bucket=1, transfers=9),
@@ -64,39 +58,28 @@ def read(name, run):
     return spec.load_module(spec.BENCH_DIR / "metrics" / f"{name}.py").read(run)
 
 
-STAGES = ("pack", "window_plan", "run", "unpack", "runtime")
+STAGES = ("pack", "run", "unpack", "runtime")
 
 
 def test_steps_inside_the_window_that_ran_a_batch(run):
     found = progspans.steps(run)
     assert [s.attrs["kind"] for s in found] == ["list", "count", "topk"]
-    assert [len(s.events) for s in found] == [7, 4, 7]
+    assert [len(s.events) for s in found] == [5, 4, 4]
 
 
 def test_a_stage_inside_another_counts_toward_the_outer(run):
     ms = {name: read(f"{name}_ms_per_step", run) for name in STAGES}
-    # per step: pack 1, 2, 1; window plan 4, 0, 2 (its pack and run
-    # inside); run 11, 5, 4; unpack 2, 1, 1; runtime 20-18, 10-8, 10-8
-    assert ms == pytest.approx({"pack": 4 / 3, "window_plan": 2, "run": 20 / 3,
-                                "unpack": 4 / 3, "runtime": 2})
-    # the five stages partition the step
+    # per step: pack 2, 2, 1 (not the pack inside the list step's run);
+    # run 14, 5, 6; unpack 2, 1, 1; runtime 20-18, 10-8, 10-8
+    assert ms == pytest.approx({"pack": 5 / 3, "run": 25 / 3, "unpack": 4 / 3,
+                                "runtime": 2})
+    # the four stages partition the step
     assert sum(ms.values()) == pytest.approx(40 / 3)
-    assert read("transfers_per_step", run) == pytest.approx(13)
+    assert read("transfers_per_step", run) == pytest.approx(7)
 
 
-def test_useful_row_share_of_list_and_topk(run):
-    list_step, count_step, topk_step = progspans.steps(run)
-    # list: 3 assigned rows of 3 executors x 4 padded rows
-    assert progspans.useful_row_share([list_step]) == pytest.approx(3 / 12)
-    # top-k: 1 of 2 executors x 1 padded row
-    assert progspans.useful_row_share([topk_step]) == pytest.approx(1 / 2)
-    assert progspans.useful_row_share([count_step]) is None
-    assert read("useful_row_share", run) == pytest.approx(4 / 14)
-
-
-NEW = ("pack_ms_per_step", "window_plan_ms_per_step", "run_ms_per_step",
-       "unpack_ms_per_step", "runtime_ms_per_step", "transfers_per_step",
-       "useful_row_share")
+NEW = ("pack_ms_per_step", "run_ms_per_step", "unpack_ms_per_step",
+       "runtime_ms_per_step", "transfers_per_step")
 
 
 @pytest.mark.parametrize("case", ["no_events", "dropped", "no_module"])
